@@ -3,7 +3,7 @@ table from the dry-run.  Prints ``name,us_per_call,derived`` CSV.
 
   PYTHONPATH=src python -m benchmarks.run            # quick mode
   REPRO_BENCH_FULL=1 ... python -m benchmarks.run    # ~10x sizes
-  python -m benchmarks.run --only fig4,roofline
+  python -m benchmarks.run --only fig4,roofline   # roofline: on request only
   python -m benchmarks.run --smoke                   # tiny CI gate (tier-1)
 """
 from __future__ import annotations
@@ -11,6 +11,7 @@ from __future__ import annotations
 import argparse
 import sys
 import time
+import traceback
 
 
 def smoke() -> int:
@@ -380,16 +381,19 @@ def smoke() -> int:
     return 0 if ok else 1
 
 
-def main() -> None:
+def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default="",
-                    help="comma-separated subset: fig4..fig12,roofline")
+                    help="comma-separated subset: fig4..fig12, fig_*, or "
+                         "roofline (needs results/dryrun.json; never run "
+                         "by default)")
     ap.add_argument("--smoke", action="store_true",
                     help="tiny all-engine assertion run (CI gate)")
     args = ap.parse_args()
+    from repro.utils import enable_compile_cache
+    enable_compile_cache()
     if args.smoke:
-        sys.exit(smoke())
-    only = set(args.only.split(",")) if args.only else None
+        return smoke()
 
     from benchmarks import (common, fig4_put, fig5_get, fig6_scan,
                             fig7_scan_length, fig8_ycsb, fig9_scalability,
@@ -412,13 +416,18 @@ def main() -> None:
         "fig_shard": fig_shard.run,
         "fig_tail": fig_tail.run,
         "fig_trace": fig_trace.run,
-        "roofline": roofline.run,
     }
+    on_request = {"roofline": roofline.run}
+    only = [n for n in args.only.split(",") if n]
+    unknown = sorted(set(only) - set(suites) - set(on_request))
+    if unknown:
+        ap.error(f"unknown suite(s): {','.join(unknown)}")
+    chosen = only or list(suites)
     print("name,us_per_call,derived")
     t0 = time.time()
-    for name, fn in suites.items():
-        if only and name not in only:
-            continue
+    failed = []
+    for name in chosen:
+        fn = suites.get(name) or on_request[name]
         t1 = time.time()
         try:
             rows = fn()
@@ -427,9 +436,14 @@ def main() -> None:
             print(f"# wrote {path}", file=sys.stderr)
         except Exception as e:  # a failed suite must not hide the others
             print(f"{name}/SUITE_ERROR,0,{e!r}")
+            traceback.print_exc()
+            failed.append(name)
         print(f"# {name} done in {time.time() - t1:.1f}s", file=sys.stderr)
     print(f"# total {time.time() - t0:.1f}s", file=sys.stderr)
+    if failed:
+        print(f"# failed suites: {','.join(failed)}", file=sys.stderr)
+    return 1 if failed else 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

@@ -4,17 +4,26 @@
   2. crash it, restore from the last committed manifest, finish training,
   3. serve it with the paged-KV engine and run a cache GC.
 
-  PYTHONPATH=src python examples/quickstart.py
+  PYTHONPATH=src python examples/quickstart.py --kv-backend pallas_interpret
+
+(--kv-backend defaults to the compiled kv_compaction kernel, which needs a
+TPU.)
 """
+import argparse
 import shutil
 import tempfile
 
 import jax
 
 from repro.configs import ShapeConfig, get
+from repro.kernels.kv_compaction.ops import BACKENDS
 from repro.launch.mesh import make_host_mesh
 from repro.runtime.coordinator import TrainRunner
 from repro.serve.engine import ServingEngine
+
+ap = argparse.ArgumentParser()
+ap.add_argument("--kv-backend", default="pallas", choices=BACKENDS)
+backend = ap.parse_args().kv_backend
 
 cfg = get("smollm_135m", smoke=True)
 shape = ShapeConfig("qs", seq_len=32, global_batch=4, kind="train")
@@ -46,8 +55,8 @@ for p in ([3, 1, 4], [1, 5, 9, 2], [6, 5, 3]):
 eng.run_until_drained()
 print(f"   served {len(eng.finished)} requests; "
       f"fragmentation={eng.fragmentation():.2f}")
-eng.compact(backend="reference")
-print(f"   after cache GC: fragmentation={eng.fragmentation():.2f}")
+eng.compact(backend=backend)
+print(f"   after cache GC ({backend}): fragmentation={eng.fragmentation():.2f}")
 for r in eng.finished:
     print(f"   req{r.rid}: {r.prompt} -> {r.out}")
 shutil.rmtree(wd, ignore_errors=True)

@@ -2,14 +2,22 @@
 block pool, then a Nezha-style cache GC (the kv_compaction kernel) restoring
 contiguous layout — outputs are bit-identical before/after.
 
-  PYTHONPATH=src python examples/serve_paged.py
+  PYTHONPATH=src python examples/serve_paged.py                 # on a TPU
+  PYTHONPATH=src python examples/serve_paged.py --kv-backend pallas_interpret
 """
+import argparse
+
 import jax
 import numpy as np
 
 from repro.configs import get
+from repro.kernels.kv_compaction.ops import BACKENDS
 from repro.models import init_params
 from repro.serve.engine import ServingEngine
+
+ap = argparse.ArgumentParser()
+ap.add_argument("--kv-backend", default="pallas", choices=BACKENDS)
+backend = ap.parse_args().kv_backend
 
 cfg = get("smollm_135m", smoke=True).replace(param_dtype="float32",
                                              kv_block_size=8)
@@ -27,8 +35,8 @@ print(f"   {tok} tokens across {eng.decode_steps} lockstep decode steps")
 print(f"   block-table fragmentation: {eng.fragmentation():.2f} "
       f"(scattered ValueLog state)")
 
-print("== Nezha cache GC (kv_compaction Pallas kernel, interpret mode) ==")
-eng.compact(backend="pallas_interpret")
+print(f"== Nezha cache GC (kv_compaction kernel, backend {backend}) ==")
+eng.compact(backend=backend)
 print(f"   fragmentation after GC: {eng.fragmentation():.2f} "
       f"(sorted ValueLog state)")
 

@@ -10,15 +10,10 @@ from repro.kernels.flash_attention.kernel import flash_attention_pallas
 from repro.kernels.flash_attention.ref import flash_attention_ref
 
 
-def default_backend() -> str:
-    return "pallas" if jax.default_backend() == "tpu" else "reference"
-
-
 @functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k",
                                              "backend"))
 def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 512,
-                    block_k: int = 512, backend: str = None):
-    backend = backend or default_backend()
+                    block_k: int = 512, backend: str):
     if backend == "reference":
         return flash_attention_ref(q, k, v, causal=causal)
     return flash_attention_pallas(

@@ -14,11 +14,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import pallas_compiler_params
-
-_compiler_params = pallas_compiler_params(pltpu)
-
-
 
 def _compact_kernel(table_ref, pool_ref, out_ref):
     out_ref[0, 0] = pool_ref[0, 0]
@@ -44,7 +39,7 @@ def compact_kv_pool_pallas(pool, table, *, interpret: bool = False):
         _compact_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(table, pool)

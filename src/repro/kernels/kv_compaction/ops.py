@@ -9,15 +9,16 @@ import jax.numpy as jnp
 from repro.kernels.kv_compaction.kernel import compact_kv_pool_pallas
 from repro.kernels.kv_compaction.ref import compact_kv_pool_ref
 
-
-def default_backend() -> str:
-    return "pallas" if jax.default_backend() == "tpu" else "reference"
+# the caller's choice, never inferred: the compiled TPU kernel, the same
+# kernel interpreted (CPU validation), or the pure-jnp oracle
+BACKENDS = ("pallas", "pallas_interpret", "reference")
 
 
 @functools.partial(jax.jit, static_argnames=("backend",))
-def compact_kv_pool(pool, table, *, backend: str = None):
+def compact_kv_pool(pool, table, *, backend: str):
     """Returns (compacted_pool, identity_table)."""
-    backend = backend or default_backend()
+    if backend not in BACKENDS:
+        raise ValueError(f"backend {backend!r} is not one of {BACKENDS}")
     if backend == "reference":
         out = compact_kv_pool_ref(pool, table)
     else:

@@ -20,10 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import pallas_compiler_params
-
-_compiler_params = pallas_compiler_params(pltpu)
-
 
 NEG_INF = -1e30
 
@@ -109,7 +105,7 @@ def paged_decode_attention_pallas(q, pool_k, pool_v, table, length, *,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, nkv, rep, hd), q.dtype),
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(lengths, table, qg, pool_k, pool_v)

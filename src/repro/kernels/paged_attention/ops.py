@@ -9,14 +9,9 @@ from repro.kernels.paged_attention.kernel import paged_decode_attention_pallas
 from repro.kernels.paged_attention.ref import paged_decode_attention_ref
 
 
-def default_backend() -> str:
-    return "pallas" if jax.default_backend() == "tpu" else "reference"
-
-
 @functools.partial(jax.jit, static_argnames=("backend",))
 def paged_decode_attention(q, pool_k, pool_v, table, length, *,
-                           backend: str = None):
-    backend = backend or default_backend()
+                           backend: str):
     if backend == "reference":
         return paged_decode_attention_ref(q, pool_k, pool_v, table, length)
     return paged_decode_attention_pallas(

@@ -10,27 +10,18 @@ everything else stays intra-pod.
 from __future__ import annotations
 
 import jax
-
-from repro.compat import AxisType  # None when the installed jax lacks it
-
-
-def mesh_axis_kwargs(n_axes: int) -> dict:
-    """kwargs for jax.make_mesh that request Auto axes when the installed
-    jax supports explicit axis types, and nothing otherwise."""
-    if AxisType is None:
-        return {}
-    return {"axis_types": (AxisType.Auto,) * n_axes}
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **mesh_axis_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
-def make_host_mesh(model: int = 1):
-    """Mesh over whatever devices exist locally (smoke tests / examples)."""
-    n = len(jax.devices())
-    data = n // model
-    return jax.make_mesh((data, model), ("data", "model"),
-                         **mesh_axis_kwargs(2))
+def make_host_mesh(model: int = 1, devices=None):
+    """(data, model) mesh over `devices` — every local device by default;
+    pass `jax.devices()[:1]` to pin a run to one chip."""
+    devices = jax.devices() if devices is None else list(devices)
+    return jax.make_mesh((len(devices) // model, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2, devices=devices)

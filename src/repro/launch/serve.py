@@ -2,6 +2,9 @@
 
   PYTHONPATH=src python -m repro.launch.serve --arch smollm_135m --smoke \
       --requests 8 --max-new 12 --compact-every 4
+
+The cache GC runs the compiled kv_compaction kernel (a TPU); on a CPU pass
+--kv-backend pallas_interpret (the same kernel, interpreted) or reference.
 """
 from __future__ import annotations
 
@@ -10,6 +13,8 @@ import time
 
 
 def main():
+    from repro.kernels.kv_compaction.ops import BACKENDS
+
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="smollm_135m")
     ap.add_argument("--smoke", action="store_true")
@@ -19,14 +24,21 @@ def main():
     ap.add_argument("--max-seq", type=int, default=128)
     ap.add_argument("--compact-every", type=int, default=0,
                     help="run cache GC every N finished requests")
+    ap.add_argument("--kv-backend", default="pallas", choices=BACKENDS,
+                    help="cache GC kernel (see kernels/kv_compaction/ops.py)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    import jax
     import numpy as np
 
     from repro.configs import get
     from repro.serve.engine import ServingEngine
+    from repro.utils import enable_compile_cache
 
+    enable_compile_cache()
+    print(f"[serve] device {jax.devices()[0].device_kind}, cache GC backend "
+          f"{args.kv_backend}")
     cfg = get(args.arch, smoke=args.smoke)
     eng = ServingEngine(cfg, max_slots=args.slots, max_seq=args.max_seq,
                         seed=args.seed)
@@ -43,9 +55,9 @@ def main():
         if newly and args.compact_every and \
                 len(eng.finished) % args.compact_every == 0:
             frag = eng.fragmentation()
-            eng.compact(backend="reference")
-            print(f"[serve] cache GC: fragmentation {frag:.2f} -> "
-                  f"{eng.fragmentation():.2f}")
+            eng.compact(backend=args.kv_backend)
+            print(f"[serve] cache GC ({args.kv_backend}): fragmentation "
+                  f"{frag:.2f} -> {eng.fragmentation():.2f}")
         done = len(eng.finished)
     dt = time.time() - t0
     tokens = sum(len(r.out) for r in eng.finished)

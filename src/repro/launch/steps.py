@@ -57,10 +57,11 @@ def batch_shardings(cfg, shape, rules: Rules):
     return specs
 
 
-def abstract_state(cfg, key=jax.random.PRNGKey(0)):
+def abstract_state(cfg, key=None):
     """Abstract (ShapeDtypeStruct) train state, never materialized."""
     def mk():
-        params = init_params(key, cfg)
+        params = init_params(jax.random.PRNGKey(0) if key is None else key,
+                             cfg)
         m, v = init_opt_state(params)
         return {"params": params, "m": m, "v": v,
                 "step": jnp.zeros((), jnp.int32)}

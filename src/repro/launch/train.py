@@ -32,7 +32,9 @@ def main():
     from repro.configs import ShapeConfig, get
     from repro.launch.mesh import make_host_mesh
     from repro.runtime.coordinator import Coordinator, TrainRunner
+    from repro.utils import enable_compile_cache
 
+    enable_compile_cache()
     cfg = get(args.arch, smoke=args.smoke)
     shape = ShapeConfig("cli", seq_len=args.seq, global_batch=args.batch,
                         kind="train")

@@ -19,7 +19,6 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from repro.compat import shard_map as _shard_map
 from repro.models.layers import dense_init
 
 
@@ -117,7 +116,7 @@ def moe_apply(params, x, cfg, rules):
         return out.reshape(B, S, d)
     fn = functools.partial(_moe_local, cfg=cfg, E_local=E_local,
                            model_axis="model")
-    out = _shard_map(
+    out = jax.shard_map(
         fn, mesh=mesh,
         in_specs=(P(dp, None), P(None, None), P("model", None, None),
                   P("model", None, None), P("model", None, None)),

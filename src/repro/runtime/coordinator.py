@@ -93,7 +93,9 @@ class TrainRunner:
             f"{workdir}/ckpt", keep=keep,
             cluster=coordinator.cluster if coordinator else None)
         self.state = None
-        self.start_step = 0
+        self.start_step = 0              # the step the next run() starts at
+        self.save_seconds: List[float] = []     # per save: fetch + store
+        self.restore_seconds: Optional[float] = None
 
     def init_or_restore(self):
         latest = self.store.latest_step()
@@ -101,13 +103,13 @@ class TrainRunner:
             self.state = self.init_fn(jax.random.PRNGKey(self.seed))
             self.start_step = 0
         else:
-            template = jax.eval_shape(
-                lambda: steps_lib.abstract_state(self.cfg))
+            t0 = time.perf_counter()
             host_tree, step = self.store.restore(
                 steps_lib.abstract_state(self.cfg))
-            self.state = jax.tree.map(
+            self.state = jax.block_until_ready(jax.tree.map(
                 lambda arr, sh: jax.device_put(arr, sh),
-                host_tree, self.st_sh)
+                host_tree, self.st_sh))
+            self.restore_seconds = time.perf_counter() - t0
             self.start_step = step
         return self.start_step
 
@@ -116,10 +118,11 @@ class TrainRunner:
                 for k, v in batch.items()}
 
     def run(self, n_steps: int, crash_at: Optional[int] = None) -> List[float]:
-        """Returns per-step losses. crash_at simulates a host failure by
-        raising after that step commits (state is NOT checkpointed then
-        unless on the ckpt_every boundary — restart resumes from the last
-        committed manifest)."""
+        """Runs from start_step up to n_steps and returns their losses; a
+        second call continues where the first stopped.  crash_at simulates
+        a host failure by raising after that step commits (state is NOT
+        checkpointed then unless on the ckpt_every boundary — restart
+        resumes from the last committed manifest)."""
         pipe = TokenPipeline(self.cfg, self.shape, seed=self.seed,
                              start_step=self.start_step)
         losses = []
@@ -132,11 +135,14 @@ class TrainRunner:
                 if self.coord is not None:
                     self.coord.commit("step", {"step": step, "loss": loss})
                     self.coord.heartbeat(0, step, time.time())
+                self.start_step = step + 1
                 if (step + 1) % self.ckpt_every == 0:
+                    t0 = time.perf_counter()
                     host_state = jax.tree.map(np.asarray, self.state)
                     self.store.save(step + 1, host_state)
                     if self.coord is not None:
                         self.coord.commit("ckpt", {"step": step + 1})
+                    self.save_seconds.append(time.perf_counter() - t0)
                 if crash_at is not None and step + 1 == crash_at:
                     raise RuntimeError(f"injected host failure at {crash_at}")
         finally:

@@ -182,36 +182,38 @@ class ServingEngine:
             total += t.size
         return 1.0 - ident / max(total, 1)
 
-    def compact(self, backend: str = None):
-        """Nezha GC for the KV pool: gather every live sequence's blocks into
-        logical order and reset tables to identity.  Old pool remains valid
-        until the per-layer swap (three-phase read safety)."""
-        def fix(path, a):
-            return a
-        # operate per attention cache group: pool_k/pool_v/table triplets
-        def compact_group(group):
-            if "pool_k" not in group:
-                return group
-            pk, pv, tb = group["pool_k"], group["pool_v"], group["table"]
-            shp = pk.shape                     # (reps, B, nblk, bs, nkv, hd)
-            flat_k = pk.reshape((-1,) + shp[2:4] + (shp[4] * shp[5],))
-            flat_v = pv.reshape((-1,) + shp[2:4] + (shp[4] * shp[5],))
-            flat_t = jnp.broadcast_to(tb, shp[:2] + tb.shape[2:]).reshape(
-                (-1, tb.shape[-1]))
-            new_k, ident = compact_kv_pool(flat_k, flat_t, backend=backend)
-            new_v, _ = compact_kv_pool(flat_v, flat_t, backend=backend)
-            return dict(group,
-                        pool_k=new_k.reshape(shp), pool_v=new_v.reshape(shp),
-                        table=ident.reshape(tb.shape))
-
-        def walk(tree):
-            if isinstance(tree, dict):
-                if "pool_k" in tree:
-                    return compact_group(tree)
-                return {k: walk(v) for k, v in tree.items()}
-            if isinstance(tree, (list, tuple)):
-                return type(tree)(walk(v) for v in tree)
-            return tree
-
-        self.caches = walk(self.caches)
+    def compact(self, backend: str):
+        """Nezha GC for the KV pool (see `compact_caches`); `backend` picks
+        the compaction kernel (repro.kernels.kv_compaction.ops)."""
+        self.caches = compact_caches(self.caches, backend)
         self.compactions += 1
+
+
+def compact_caches(caches, backend: str):
+    """Gather every live sequence's blocks into logical order and reset the
+    tables to identity.  Returns new caches; the old pool stays valid until
+    the caller swaps it in (three-phase read safety)."""
+    def compact_group(group):
+        pk, pv, tb = group["pool_k"], group["pool_v"], group["table"]
+        shp = pk.shape                     # (reps, B, nblk, bs, nkv, hd)
+        flat_k = pk.reshape((-1,) + shp[2:4] + (shp[4] * shp[5],))
+        flat_v = pv.reshape((-1,) + shp[2:4] + (shp[4] * shp[5],))
+        flat_t = jnp.broadcast_to(tb, shp[:2] + tb.shape[2:]).reshape(
+            (-1, tb.shape[-1]))
+        new_k, ident = compact_kv_pool(flat_k, flat_t, backend=backend)
+        new_v, _ = compact_kv_pool(flat_v, flat_t, backend=backend)
+        return dict(group,
+                    pool_k=new_k.reshape(shp), pool_v=new_v.reshape(shp),
+                    table=ident.reshape(tb.shape))
+
+    # operate per attention cache group: pool_k/pool_v/table triplets
+    def walk(tree):
+        if isinstance(tree, dict):
+            if "pool_k" in tree:
+                return compact_group(tree)
+            return {k: walk(v) for k, v in tree.items()}
+        if isinstance(tree, (list, tuple)):
+            return type(tree)(walk(v) for v in tree)
+        return tree
+
+    return walk(caches)

@@ -1,10 +1,27 @@
 """Small shared utilities."""
 from __future__ import annotations
 
+import os
+from pathlib import Path
 from typing import Any, Tuple
 
 import jax
 import numpy as np
+
+# <checkout>/.jax_cache: fixed, so the path part of the cache key never moves
+COMPILE_CACHE_DIR = Path(__file__).resolve().parents[2] / ".jax_cache"
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for an entry point.
+    Where JAX_COMPILATION_CACHE_DIR is set, JAX already reads it and this
+    sets nothing; otherwise the cache lives in COMPILE_CACHE_DIR.  Returns
+    the directory in use."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", str(COMPILE_CACHE_DIR))
+    return str(COMPILE_CACHE_DIR)
 
 
 def path_str(path: Tuple[Any, ...]) -> str:

@@ -132,3 +132,10 @@ def test_paged_attention_invariant_under_compaction():
         backend="pallas_interpret")
     np.testing.assert_allclose(np.asarray(before), np.asarray(after),
                                rtol=1e-6, atol=1e-6)
+
+
+def test_compaction_rejects_an_unknown_backend():
+    pool = jnp.zeros((1, 2, 8, 32), jnp.float32)
+    table = jnp.zeros((1, 2), jnp.int32)
+    with pytest.raises(ValueError, match="not one of"):
+        compact_kv_pool(pool, table, backend="pallas_compiled")

@@ -11,7 +11,7 @@ import pytest
 
 from repro.launch.hlo_analysis import HloModule, analyze, type_bytes
 from repro.sharding.rules import fit_spec, make_rules, param_spec
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AxisType, PartitionSpec as P
 
 
 def test_type_bytes():
@@ -54,13 +54,12 @@ def test_analyzer_nested_scans():
 
 
 def _mesh():
-    from repro.launch.mesh import mesh_axis_kwargs
-    return jax.make_mesh((1, 1), ("data", "model"), **mesh_axis_kwargs(2))
+    return jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def test_fit_spec_drops_indivisible_axes():
-    from repro.launch.mesh import mesh_axis_kwargs
-    mesh = jax.make_mesh((1,), ("data",), **mesh_axis_kwargs(1))
+    mesh = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
     assert fit_spec((7,), P("data"), mesh) == P("data")  # 7 % 1 == 0
     # batch=1 cannot shard over a >1 axis — simulated via spec entries
     rules = make_rules(_mesh())
@@ -83,9 +82,10 @@ SUBPROC = textwrap.dedent("""
     os.environ["JAX_PLATFORMS"] = "cpu"  # 8 host devices, never real TPU
     import jax, jax.numpy as jnp, json
     from repro.configs import get, ShapeConfig
-    from repro.launch.mesh import mesh_axis_kwargs
+    from jax.sharding import AxisType
     from repro.launch.steps import make_train_step, make_init_fn, input_specs
-    mesh = jax.make_mesh((4, 2), ("data", "model"), **mesh_axis_kwargs(2))
+    mesh = jax.make_mesh((4, 2), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     out = {}
     for arch in ["smollm_135m", "olmoe_1b_7b", "zamba2_1p2b"]:
         cfg = get(arch, smoke=True)
