@@ -25,6 +25,7 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import numpy as np
 
+from repro.core import trace
 from repro.core.metrics import Metrics
 from repro.utils import path_str
 
@@ -32,27 +33,32 @@ PyTree = Any
 
 
 class _Vlog:
-    def __init__(self, path: str, metrics: Metrics, category: str):
+    """One value-log file.  Bytes are counted under the category of the
+    work that moves them (a save's append, GC's rewrite), not of the file."""
+
+    def __init__(self, path: str, metrics: Metrics):
         self.path = path
         self.metrics = metrics
-        self.category = category
         os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
         self._f = open(path, "ab")
         self.size = self._f.tell()
 
-    def append(self, data: bytes) -> int:
+    def append(self, data: bytes, category: str) -> int:
         off = self.size
         self._f.write(data)
         self.size += len(data)
-        self.metrics.on_write(self.category, len(data))
+        self.metrics.on_write(category, len(data))
         return off
 
-    def read(self, off: int, length: int) -> bytes:
+    def flush(self):
         self._f.flush()
+
+    def read(self, off: int, length: int) -> bytes:
+        self.flush()
         with open(self.path, "rb") as f:
             f.seek(off)
             data = f.read(length)
-        self.metrics.on_read(self.category, length)
+        self.metrics.on_read("ckpt_valuelog", length)
         return data
 
     def close(self):
@@ -76,7 +82,7 @@ class NezhaCheckpointStore:
         self.gc_threshold = gc_threshold_bytes
         self.gen = 0
         self.vlog = _Vlog(os.path.join(dirpath, f"ckpt_{self.gen:04d}.vlog"),
-                          self.metrics, "ckpt_valuelog")
+                          self.metrics)
         self.manifests: Dict[int, dict] = {}       # step -> manifest
         self._manifest_dir = os.path.join(dirpath, "manifests")
         os.makedirs(self._manifest_dir, exist_ok=True)
@@ -86,15 +92,17 @@ class NezhaCheckpointStore:
     def save(self, step: int, tree: PyTree, host_id: int = 0) -> dict:
         flat, _ = jax.tree_util.tree_flatten_with_path(tree)
         entries = {}
-        for path, leaf in flat:
-            arr = np.asarray(leaf)
-            data = arr.tobytes()
-            off = self.vlog.append(data)            # the ONE tensor write
-            entries[path_str(path)] = {
-                "gen": self.gen, "offset": off, "length": len(data),
-                "dtype": str(arr.dtype), "shape": list(arr.shape),
-                "host": host_id,
-            }
+        with trace.span("ckpt.store.append"):
+            for path, leaf in flat:
+                arr = np.asarray(leaf)
+                data = arr.tobytes()
+                # the ONE tensor write
+                off = self.vlog.append(data, "ckpt_valuelog")
+                entries[path_str(path)] = {
+                    "gen": self.gen, "offset": off, "length": len(data),
+                    "dtype": str(arr.dtype), "shape": list(arr.shape),
+                    "host": host_id,
+                }
         manifest = {"step": step, "entries": entries,
                     "vlog_gen": self.gen}
         self._commit_manifest(step, manifest)
@@ -103,16 +111,17 @@ class NezhaCheckpointStore:
         return manifest
 
     def _commit_manifest(self, step: int, manifest: dict):
-        blob = json.dumps(manifest).encode()
-        if self.cluster is not None:
-            # lightweight metadata through consensus (KVS-Raft style)
-            self.cluster.put(f"ckpt_manifest/{step:012d}".encode(), blob)
-        path = os.path.join(self._manifest_dir, f"{step:012d}.json")
-        tmp = path + ".tmp"
-        with open(tmp, "wb") as f:
-            f.write(blob)
-        os.replace(tmp, path)                       # atomic commit point
-        self.metrics.on_write("ckpt_manifest", len(blob))
+        with trace.span("ckpt.store.manifest"):
+            blob = json.dumps(manifest).encode()
+            if self.cluster is not None:
+                # lightweight metadata through consensus (KVS-Raft style)
+                self.cluster.put(f"ckpt_manifest/{step:012d}".encode(), blob)
+            path = os.path.join(self._manifest_dir, f"{step:012d}.json")
+            tmp = path + ".tmp"
+            with open(tmp, "wb") as f:
+                f.write(blob)
+            os.replace(tmp, path)                   # atomic commit point
+            self.metrics.on_write("ckpt_manifest", len(blob))
 
     def _load_manifests(self):
         for fn in sorted(os.listdir(self._manifest_dir)):
@@ -160,7 +169,8 @@ class NezhaCheckpointStore:
     # ----------------------------------------------------------------- GC
     def _maybe_gc(self):
         if self.vlog.size >= self.gc_threshold:
-            self.gc()
+            with trace.span("ckpt.store.gc"):
+                self.gc()
 
     def gc(self):
         """Compact live checkpoints into a fresh, NAME-SORTED ValueLog.
@@ -168,15 +178,16 @@ class NezhaCheckpointStore:
         live_steps = sorted(self.manifests)[-self.keep:]
         old_gens = {self.manifests[s]["vlog_gen"] for s in live_steps} | \
             {self.gen}
+        self.vlog.flush()           # GC reads the log it leaves by its path
         self.gen += 1
         new_vlog = _Vlog(os.path.join(self.dir, f"ckpt_{self.gen:04d}.vlog"),
-                         self.metrics, "ckpt_gc")
+                         self.metrics)
         for s in live_steps:
             man = self.manifests[s]
             for name in sorted(man["entries"]):     # key-sorted layout
                 e = man["entries"][name]
                 data = self._read_entry(e)
-                e["offset"] = new_vlog.append(data)
+                e["offset"] = new_vlog.append(data, "ckpt_gc")
                 e["gen"] = self.gen
             man["vlog_gen"] = self.gen
             self._commit_manifest(s, man)

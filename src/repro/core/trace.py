@@ -29,11 +29,23 @@ The tracer is installed process-globally (same pattern as
 ``faultfs.install``): hot paths pay one ``_ACTIVE is None`` check when
 tracing is off, and installing/uninstalling never perturbs the
 simulation (no RNG draws, no virtual-time advances).
+
+The device paths (checkpoint save/restore, the serving engine) open spans
+through the module-level ``span``/``record``: into the installed tracer
+when there is one, else, while a JAX profiler session is recording, into a
+process-wide profile tracer on ``time.perf_counter`` whose context spans
+are also ``jax.profiler.TraceAnnotation``s, so they sit in the profile on
+the device's clock; ``profiled()`` returns it.  Otherwise a span is one
+shared no-op.  This module never imports JAX: the gate looks for a JAX
+that is already imported.
 """
 
 from __future__ import annotations
 
+import contextlib
 import json
+import sys
+import time
 from typing import Any, Callable, Dict, Iterable, List, Optional, Tuple
 
 # --------------------------------------------------------------- spans
@@ -88,8 +100,19 @@ class Tracer:
     I/O hook fires is, by construction, the work that caused it.
     """
 
-    def __init__(self, clock: Optional[Callable[[], int]] = None):
+    def __init__(self, clock: Optional[Callable[[], int]] = None, *,
+                 annotate: bool = False, max_spans: Optional[int] = None):
         self.clock: Callable[[], int] = clock or (lambda: 0)
+        # annotate: each span opened by begin() is also a profiler
+        # TraceAnnotation of its name, entered in begin() and left in end()
+        # (context spans only, which nest LIFO)
+        self._annotation = None
+        if annotate:
+            from jax.profiler import TraceAnnotation
+            self._annotation = TraceAnnotation
+        self._open_annotations: Dict[int, Any] = {}
+        self.max_spans = max_spans
+        self.dropped = 0                            # spans past max_spans
         self.spans: List[Span] = []
         self.events: List[Dict[str, Any]] = []      # causality audit stream
         self.net_events: List[Tuple] = []           # unified SimNet feed
@@ -109,6 +132,8 @@ class Tracer:
         """Open a span and push it on the stack.  ``parent=None`` nests
         under the current top of stack; pass an explicit id (e.g. a ctx
         carried on a message) to graft a remote child."""
+        if self._full():
+            return 0
         sid = self._next
         self._next += 1
         if parent is None:
@@ -119,9 +144,16 @@ class Tracer:
         self._by_id[sid] = sp
         self.spans.append(sp)
         self._stack.append(sid)
+        if self._annotation is not None:
+            ann = self._annotation(name)
+            ann.__enter__()
+            self._open_annotations[sid] = ann
         return sid
 
     def end(self, sid: int) -> None:
+        ann = self._open_annotations.pop(sid, None)
+        if ann is not None:
+            ann.__exit__(None, None, None)
         sp = self._by_id.get(sid)
         if sp is not None and sp.t1 is None:
             sp.t1 = self.clock()
@@ -132,6 +164,25 @@ class Tracer:
 
     def span(self, name: str, **kw: Any) -> _SpanCtx:
         return _SpanCtx(self, name, kw)
+
+    def record(self, name: str, t0: Any, t1: Any, **tags: Any) -> None:
+        """Add a completed span (kind ``record``) from two stamps on this
+        tracer's clock, as a child of the current span."""
+        if self._full():
+            return
+        sid = self._next
+        self._next += 1
+        sp = Span(sid, self._stack[-1] if self._stack else 0, name,
+                  "record", None, t0, tags or None)
+        sp.t1 = t1
+        self._by_id[sid] = sp
+        self.spans.append(sp)
+
+    def _full(self) -> bool:
+        if self.max_spans is not None and len(self.spans) >= self.max_spans:
+            self.dropped += 1
+            return True
+        return False
 
     def current(self) -> int:
         """Span id to stamp into an outgoing message (0 = no context)."""
@@ -286,6 +337,60 @@ def uninstall() -> None:
 
 def active() -> Optional[Tracer]:
     return _ACTIVE
+
+
+# ------------------------------------------- device paths: profile tracer
+
+PROFILE_MAX_SPANS = 1_000_000
+_NOOP = contextlib.nullcontext()
+_PROFILE: Optional[Tracer] = None       # the latest profiler session's
+_PROFILE_SESSION: Any = None            # the session it belongs to
+_SEEN_OFF = True                        # a gate found no session since
+
+
+def _profile_tracer() -> Optional[Tracer]:
+    """The profile tracer while a JAX profiler session records, else None.
+
+    A session is told apart by JAX's own session object where
+    ``jax.profiler.start_trace`` made it (a capture through
+    ``jax.profiler.start_server`` has none), and by a gate that found no
+    session between two spans: either starts a fresh tracer."""
+    global _PROFILE, _PROFILE_SESSION, _SEEN_OFF
+    prof = sys.modules.get("jax._src.profiler")
+    if prof is None:                    # no JAX imported: no session
+        return None
+    if not prof.TraceAnnotation.is_enabled():
+        _SEEN_OFF = True
+        return None
+    session = prof._profile_state.profile_session
+    if _PROFILE is None or _SEEN_OFF or session is not _PROFILE_SESSION:
+        _PROFILE = Tracer(clock=time.perf_counter, annotate=True,
+                          max_spans=PROFILE_MAX_SPANS)
+        _PROFILE_SESSION = session
+        _SEEN_OFF = False
+    return _PROFILE
+
+
+def span(name: str, **tags: Any):
+    """A span of the device paths: into the installed tracer, else into the
+    profile tracer while a profiler records, else a shared no-op.  Entering
+    it gives the span id (None when off)."""
+    t = _ACTIVE or _profile_tracer()
+    return _NOOP if t is None else t.span(name, **tags)
+
+
+def record(name: str, t0: float, t1: float, **tags: Any) -> None:
+    """A completed span from two ``time.perf_counter`` stamps, under the
+    same gate as ``span``."""
+    t = _ACTIVE or _profile_tracer()
+    if t is not None:
+        t.record(name, t0, t1, **tags)
+
+
+def profiled() -> Optional[Tracer]:
+    """The latest profiler session's spans (None before the first); it
+    stays readable until a span of the next session starts a new one."""
+    return _PROFILE
 
 
 # ------------------------------------------------------ causality audit

@@ -64,6 +64,12 @@ def main():
     print(f"[serve] {len(eng.finished)} requests, {tokens} tokens in "
           f"{dt:.1f}s ({tokens / dt:.1f} tok/s), "
           f"{eng.decode_steps} decode steps, {eng.compactions} GCs")
+    # the requests' own perf_counter stamps: submit -> admission -> last token
+    for what, secs in (
+            ("queue wait", [r.admitted - r.submitted for r in eng.finished]),
+            ("latency", [r.finished - r.submitted for r in eng.finished])):
+        p50, p95 = np.percentile(secs, [50, 95])
+        print(f"[serve] {what}: p50 {p50:.3f}s, p95 {p95:.3f}s")
     for r in eng.finished[:4]:
         print(f"  req{r.rid}: prompt={r.prompt} -> {r.out[:8]}...")
 

@@ -26,6 +26,7 @@ import numpy as np
 
 from repro.ckpt.nezha_store import NezhaCheckpointStore
 from repro.configs.base import ModelConfig, ShapeConfig
+from repro.core import trace
 from repro.core.cluster import Cluster
 from repro.data.pipeline import TokenPipeline
 from repro.launch import steps as steps_lib
@@ -103,13 +104,15 @@ class TrainRunner:
             self.state = self.init_fn(jax.random.PRNGKey(self.seed))
             self.start_step = 0
         else:
-            t0 = time.perf_counter()
-            host_tree, step = self.store.restore(
-                steps_lib.abstract_state(self.cfg))
-            self.state = jax.block_until_ready(jax.tree.map(
-                lambda arr, sh: jax.device_put(arr, sh),
-                host_tree, self.st_sh))
-            self.restore_seconds = time.perf_counter() - t0
+            with trace.span("ckpt.restore"):
+                t0 = time.perf_counter()
+                host_tree, step = self.store.restore(
+                    steps_lib.abstract_state(self.cfg))
+                with trace.span("ckpt.restore.place"):
+                    self.state = jax.block_until_ready(jax.tree.map(
+                        lambda arr, sh: jax.device_put(arr, sh),
+                        host_tree, self.st_sh))
+                self.restore_seconds = time.perf_counter() - t0
             self.start_step = step
         return self.start_step
 
@@ -137,12 +140,14 @@ class TrainRunner:
                     self.coord.heartbeat(0, step, time.time())
                 self.start_step = step + 1
                 if (step + 1) % self.ckpt_every == 0:
-                    t0 = time.perf_counter()
-                    host_state = jax.tree.map(np.asarray, self.state)
-                    self.store.save(step + 1, host_state)
-                    if self.coord is not None:
-                        self.coord.commit("ckpt", {"step": step + 1})
-                    self.save_seconds.append(time.perf_counter() - t0)
+                    with trace.span("ckpt.save"):
+                        t0 = time.perf_counter()
+                        with trace.span("ckpt.save.fetch"):
+                            host_state = jax.tree.map(np.asarray, self.state)
+                        self.store.save(step + 1, host_state)
+                        if self.coord is not None:
+                            self.coord.commit("ckpt", {"step": step + 1})
+                        self.save_seconds.append(time.perf_counter() - t0)
                 if crash_at is not None and step + 1 == crash_at:
                     raise RuntimeError(f"injected host failure at {crash_at}")
         finally:

@@ -25,6 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
+from repro.core import trace
 from repro.kernels.kv_compaction.ops import compact_kv_pool
 from repro.models import forward, init_cache, init_params
 
@@ -37,7 +38,9 @@ class Request:
     out: List[int] = field(default_factory=list)
     slot: int = -1
     done: bool = False
+    # time.perf_counter() stamps: submit, admission to a slot, last token
     submitted: float = 0.0
+    admitted: float = 0.0
     finished: float = 0.0
 
 
@@ -81,7 +84,8 @@ class ServingEngine:
     # ------------------------------------------------------------- client
     def submit(self, prompt: List[int], max_new: int = 16) -> Request:
         self._rid += 1
-        req = Request(self._rid, list(prompt), max_new, submitted=time.time())
+        req = Request(self._rid, list(prompt), max_new,
+                      submitted=time.perf_counter())
         self.queue.append(req)
         return req
 
@@ -97,19 +101,24 @@ class ServingEngine:
     def _admit(self):
         while self.queue and self.free_slots:
             req = self.queue.popleft()
+            req.admitted = time.perf_counter()
+            trace.record("serve.queued", req.submitted, req.admitted)
             slot = self.free_slots.pop()
             req.slot = slot
             plen = len(req.prompt)
             assert plen + req.max_new <= self.max_seq
-            # fragmented allocation: reused slots get scrambled block order
-            sub = self._slot_cache(slot)
-            sub = self._fresh_slot_tables(sub)
-            toks = np.zeros((1, self.max_seq), np.int32)
-            toks[0, :plen] = req.prompt
-            logits, sub = self._prefill(self.params, sub, jnp.asarray(toks))
-            nxt = int(jnp.argmax(logits[0, plen - 1]))
-            req.out.append(nxt)
-            self._write_slot_cache(slot, sub)
+            with trace.span("serve.admit", prompt_tokens=plen,
+                            padded_tokens=self.max_seq):
+                # fragmented allocation: reused slots get scrambled blocks
+                sub = self._slot_cache(slot)
+                sub = self._fresh_slot_tables(sub)
+                toks = np.zeros((1, self.max_seq), np.int32)
+                toks[0, :plen] = req.prompt
+                logits, sub = self._prefill(self.params, sub,
+                                            jnp.asarray(toks))
+                nxt = int(jnp.argmax(logits[0, plen - 1]))
+                req.out.append(nxt)
+                self._write_slot_cache(slot, sub)
             self.pos[slot] = plen
             self.active[slot] = req
 
@@ -129,6 +138,10 @@ class ServingEngine:
 
     def step(self) -> int:
         """One engine iteration; returns number of tokens produced."""
+        with trace.span("serve.step"):
+            return self._step()
+
+    def _step(self) -> int:
         self._admit()
         if not self.active:
             return 0
@@ -151,7 +164,7 @@ class ServingEngine:
             produced += 1
             if len(req.out) - 1 >= req.max_new:
                 req.done = True
-                req.finished = time.time()
+                req.finished = time.perf_counter()
                 self.finished.append(req)
                 del self.active[slot]
                 self.free_slots.append(slot)
@@ -185,7 +198,8 @@ class ServingEngine:
     def compact(self, backend: str):
         """Nezha GC for the KV pool (see `compact_caches`); `backend` picks
         the compaction kernel (repro.kernels.kv_compaction.ops)."""
-        self.caches = compact_caches(self.caches, backend)
+        with trace.span("serve.compact"):
+            self.caches = compact_caches(self.caches, backend)
         self.compactions += 1
 
 

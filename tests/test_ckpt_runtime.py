@@ -57,6 +57,26 @@ def test_ckpt_gc_compacts_and_keeps_latest():
     store.close()
 
 
+def test_ckpt_counters_tell_save_appends_from_gc_rewrites():
+    """A save's appends count as ckpt_valuelog and GC's rewrites as ckpt_gc,
+    whichever value log (the first, or one a GC made) they go to."""
+    wd = tempfile.mkdtemp()
+    m = Metrics()
+    store = NezhaCheckpointStore(wd, m, gc_threshold_bytes=1, keep=2)
+    size = sum(np.asarray(l).nbytes for l in jax.tree.leaves(tiny_tree()))
+    store.save(1, tiny_tree(seed=1))   # GC 1 rewrites checkpoint 1
+    store.save(2, tiny_tree(seed=2))   # into GC 1's log; GC 2 rewrites 1, 2
+    assert store.gen == 2
+    assert m.write_bytes["ckpt_valuelog"] == 2 * size
+    assert m.write_bytes["ckpt_gc"] == 3 * size
+    for step in (1, 2):             # each rewritten whole, tail included
+        restored, _ = store.restore(tiny_tree(), step=step)
+        for a, b in zip(jax.tree.leaves(tiny_tree(seed=step)),
+                        jax.tree.leaves(restored)):
+            assert np.array_equal(np.asarray(a), np.asarray(b))
+    store.close()
+
+
 def test_ckpt_reload_from_disk():
     wd = tempfile.mkdtemp()
     store = NezhaCheckpointStore(wd)

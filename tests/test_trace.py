@@ -24,9 +24,17 @@ Load-bearing claims under test:
     JSON scrape; Cluster.registry() publishes per-node families.
   * SimNet drop attribution — dropped_msgs == sum(drop_reasons), with
     the partition/lossy/down/removed/crash_flush causes split out.
+  * Device-path spans — with no tracer installed and no profiler session
+    the checkpoint and serving paths open no span; under an installed
+    tracer they give the documented tree; under a profiler session they
+    land in a fresh profile tracer per session, each context span also a
+    TraceAnnotation of the same name and duration in the profile.
 """
+import glob
 import json
+import shutil
 import tempfile
+import time
 
 import pytest
 
@@ -394,3 +402,151 @@ def test_drops_flow_into_tracer_net_events():
         assert ("send", None) in kinds and ("deliver", None) in kinds
     finally:
         trace.uninstall()
+
+
+# ------------------------------------------------------ device-path spans
+@pytest.fixture(scope="module")
+def device_paths():
+    """A smoke TrainRunner (no control plane) and a smoke serving engine,
+    compiled once for the tests below; `drive()` runs two saves (the store
+    set to GC after each), a restore, and four requests through two slots
+    with a compaction."""
+    import jax
+
+    from repro.configs import ShapeConfig, get
+    from repro.launch.mesh import make_host_mesh
+    from repro.runtime.coordinator import TrainRunner
+    from repro.serve.engine import ServingEngine
+
+    cfg = get("smollm_135m", smoke=True)
+    wd = tempfile.mkdtemp(prefix="trace_ckpt_")
+    runner = TrainRunner(cfg, ShapeConfig("t", seq_len=32, global_batch=4,
+                                          kind="train"),
+                         make_host_mesh(), wd, seed=1, ckpt_every=2)
+    runner.store.gc_threshold = 0
+    runner.init_or_restore()
+    eng = ServingEngine(cfg.replace(param_dtype="float32", kv_block_size=8),
+                        max_slots=2, max_seq=32, seed=0)
+
+    def drive():
+        runner.run(runner.start_step + 4)
+        runner.init_or_restore()
+        reqs = [eng.submit([3 + i, 1, 4], max_new=3) for i in range(4)]
+        eng.run_until_drained()
+        eng.compact(backend="reference")
+        jax.block_until_ready(eng.caches)
+        return reqs
+
+    drive()                                         # compiles
+    yield drive
+    runner.store.close()
+    shutil.rmtree(wd, ignore_errors=True)
+
+
+def test_device_paths_record_no_span_when_tracing_is_off(device_paths,
+                                                         monkeypatch):
+    opened = []
+    for meth in ("begin", "record"):
+        orig = getattr(Tracer, meth)
+        monkeypatch.setattr(Tracer, meth, lambda self, *a, _o=orig, **k:
+                            opened.append(a[0]) or _o(self, *a, **k))
+    before = trace.profiled()
+    device_paths()
+    assert opened == []
+    assert trace.profiled() is before
+
+
+def test_device_paths_give_the_span_tree_under_an_installed_tracer(
+        device_paths):
+    t = trace.install(Tracer(clock=time.perf_counter))
+    reqs = device_paths()
+    trace.uninstall()
+    by_id = {s.sid: s for s in t.spans}
+
+    def parents(name):
+        return [by_id[s.parent].name if s.parent else None
+                for s in t.spans if s.name == name]
+
+    assert len(parents("ckpt.save")) == 2
+    assert parents("ckpt.save.fetch") == ["ckpt.save"] * 2
+    assert parents("ckpt.store.append") == ["ckpt.save"] * 2
+    assert parents("ckpt.store.gc") == ["ckpt.save"] * 2
+    assert "ckpt.store.gc" in parents("ckpt.store.manifest")
+    assert parents("ckpt.restore.place") == ["ckpt.restore"]
+    admits = [s for s in t.spans if s.name == "serve.admit"]
+    assert parents("serve.admit") == ["serve.step"] * len(reqs)
+    assert [(s.tags["prompt_tokens"], s.tags["padded_tokens"])
+            for s in admits] == [(3, 32)] * len(reqs)
+    queued = [s for s in t.spans if s.name == "serve.queued"]
+    assert len(queued) == len(reqs)
+    assert sorted(s.t1 - s.t0 for s in queued) == sorted(
+        r.admitted - r.submitted for r in reqs)
+    assert all(r.submitted <= r.admitted <= r.finished for r in reqs)
+    assert parents("serve.compact") == [None]
+    assert all(s.t1 is not None and s.t1 >= s.t0 for s in t.spans)
+
+
+def _annotations(log_dir):
+    import jax
+    path, = glob.glob(f"{log_dir}/**/*.xplane.pb", recursive=True)
+    out = {}
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    out.setdefault(e.name, []).append(
+                        (e.start_ns, e.duration_ns * 1e-9))
+    return {k: [d for _, d in sorted(v)] for k, v in out.items()}
+
+
+def test_profile_spans_are_trace_annotations_of_the_same_duration(
+        device_paths, tmp_path):
+    import jax
+    with jax.profiler.trace(str(tmp_path)):
+        device_paths()
+    t = trace.profiled()
+    assert t is not None and t.dropped == 0
+    spans = [s for s in t.spans if s.kind == "span"]
+    assert {s.name for s in spans} >= {
+        "ckpt.save", "ckpt.save.fetch", "ckpt.store.append",
+        "ckpt.store.manifest", "ckpt.store.gc", "ckpt.restore",
+        "ckpt.restore.place", "serve.step", "serve.admit", "serve.compact"}
+    assert [s.name for s in t.spans if s.kind == "record"] == \
+        ["serve.queued"] * 4
+    annotated = _annotations(tmp_path)
+    for name in {s.name for s in spans}:
+        mine = [s.t1 - s.t0 for s in sorted(spans, key=lambda s: s.t0)
+                if s.name == name]
+        assert len(annotated[name]) == len(mine), name
+        for a, b in zip(annotated[name], mine):
+            assert abs(a - b) < 50e-6, (name, a, b)
+
+
+def test_each_profiler_session_starts_a_fresh_profile_tracer(tmp_path):
+    import jax
+    with jax.profiler.trace(str(tmp_path / "one")):
+        with trace.span("a"):
+            pass
+    first = trace.profiled()
+    with trace.span("off"):                 # no session: a no-op
+        pass
+    assert trace.profiled() is first
+    assert [s.name for s in first.spans] == ["a"]
+    with jax.profiler.trace(str(tmp_path / "two")):
+        with trace.span("b"):
+            trace.record("c", 1.0, 2.0)
+    second = trace.profiled()
+    assert second is not first
+    assert [s.name for s in second.spans] == ["b", "c"]
+    assert second.spans[1].parent == second.spans[0].sid
+    assert [s.name for s in first.spans] == ["a"]
+
+
+def test_tracer_keeps_at_most_max_spans_and_counts_the_rest():
+    t = Tracer(clock=time.perf_counter, max_spans=3)
+    for i in range(4):
+        with t.span("s%d" % i):
+            t.record("r", 0.0, 1.0)
+    assert [s.name for s in t.spans] == ["s0", "r", "s1"]
+    assert t.dropped == 5
+    assert t._stack == []
