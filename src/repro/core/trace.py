@@ -387,6 +387,13 @@ def record(name: str, t0: float, t1: float, **tags: Any) -> None:
         t.record(name, t0, t1, **tags)
 
 
+def tag(sid: Optional[int], **tags: Any) -> None:
+    """Adds tags to the open span `sid` that ``span`` gave (None: off)."""
+    t = _ACTIVE or _PROFILE
+    if sid and t is not None:
+        t.tag(sid, **tags)
+
+
 def profiled() -> Optional[Tracer]:
     """The latest profiler session's spans (None before the first); it
     stays readable until a span of the next session starts a new one."""

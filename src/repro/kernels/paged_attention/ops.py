@@ -1,19 +1,44 @@
-"""jit'd dispatch for paged decode attention."""
+"""jit'd dispatch for paged decode attention and the token write."""
 from __future__ import annotations
 
 import functools
 
 import jax
 
-from repro.kernels.paged_attention.kernel import paged_decode_attention_pallas
-from repro.kernels.paged_attention.ref import paged_decode_attention_ref
+from repro.kernels.paged_attention.kernel import (
+    paged_decode_attention_pallas, write_token_pallas)
+from repro.kernels.paged_attention.ref import (paged_decode_attention_ref,
+                                               write_token_ref)
+
+# the compiled TPU kernels, the same kernels interpreted (CPU validation),
+# or the pure-jnp oracles
+BACKENDS = ("pallas", "pallas_interpret", "reference")
+
+
+def _check(backend: str) -> None:
+    if backend not in BACKENDS:
+        raise ValueError(f"backend {backend!r} is not one of {BACKENDS}")
 
 
 @functools.partial(jax.jit, static_argnames=("backend",))
-def paged_decode_attention(q, pool_k, pool_v, table, length, *,
+def paged_decode_attention(q, pool_k, pool_v, table, lengths, layer, *,
                            backend: str):
+    _check(backend)
     if backend == "reference":
-        return paged_decode_attention_ref(q, pool_k, pool_v, table, length)
+        return paged_decode_attention_ref(q, pool_k, pool_v, table, lengths,
+                                          layer)
     return paged_decode_attention_pallas(
-        q, pool_k, pool_v, table, length,
+        q, pool_k, pool_v, table, lengths, layer,
         interpret=(backend == "pallas_interpret"))
+
+
+@functools.partial(jax.jit, static_argnames=("backend",))
+def write_token(pool_k, pool_v, k_new, v_new, blk, row, layer, *,
+                backend: str):
+    """Returns (pool_k, pool_v) with each sequence's token written at
+    (layer, b, blk[b], row[b]); blk[b] out of range (nblk) writes nothing."""
+    _check(backend)
+    if backend == "reference":
+        return write_token_ref(pool_k, pool_v, k_new, v_new, blk, row, layer)
+    return write_token_pallas(pool_k, pool_v, k_new, v_new, blk, row, layer,
+                              interpret=(backend == "pallas_interpret"))

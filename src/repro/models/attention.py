@@ -18,6 +18,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.paged_attention import ops as paged_attention_ops
 from repro.models.layers import apply_rope, dense_init, rmsnorm
 
 NEG_INF = -1e30
@@ -167,11 +168,12 @@ def _decode_attend(q, k_all, v_all, pos, nh, rules):
 
 def attn_decode(params, cache, x, pos, cfg, rules):
     """One-token decode. x:(B,1,d); pos: scalar OR per-sequence (B,) index
-    (continuous batching serves ragged sequences in one lockstep batch)."""
+    (continuous batching serves ragged sequences in one lockstep batch); a
+    negative pos marks a free sequence, whose token is written nowhere."""
     B = x.shape[0]
     nh, nkv, hd, bs = cfg.n_heads, cfg.n_kv_heads, cfg.hd, cfg.kv_block_size
     pos_b = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,))
-    positions = pos_b[:, None]
+    positions = jnp.maximum(pos_b, 0)[:, None]
     q, k_new, v_new = _project_qkv(params, x, cfg, positions)
     bi = jnp.arange(B)
     if "k" in cache:  # dense / compacted layout
@@ -182,11 +184,11 @@ def attn_decode(params, cache, x, pos, cfg, rules):
             k_all = rules.constrain(k_all, "batch", "cache_seq")
             v_all = rules.constrain(v_all, "batch", "cache_seq")
     else:  # paged layout: write via block table, read via gather
-        blk = jnp.take_along_axis(cache["table"], (pos_b // bs)[:, None],
-                                  axis=1)[:, 0]                  # (B,)
-        slot = pos_b % bs
-        pool_k = cache["pool_k"].at[bi, blk, slot].set(k_new[:, 0])
-        pool_v = cache["pool_v"].at[bi, blk, slot].set(v_new[:, 0])
+        blk, slot = _token_block(cache["table"], pos_b, bs)
+        pool_k = cache["pool_k"].at[bi, blk, slot].set(k_new[:, 0],
+                                                       mode="drop")
+        pool_v = cache["pool_v"].at[bi, blk, slot].set(v_new[:, 0],
+                                                       mode="drop")
         new_cache = dict(cache, pool_k=pool_k, pool_v=pool_v)
         tbl = cache["table"][..., None, None, None]              # (B,nblk,1,1,1)
         k_all = jnp.take_along_axis(pool_k, tbl, axis=1)
@@ -202,10 +204,51 @@ def attn_decode(params, cache, x, pos, cfg, rules):
     return out, new_cache
 
 
+def _token_block(table, pos_b, bs):
+    """Physical block and row of each sequence's token at pos_b through its
+    table (B, nblk); a free (pos < 0) or full sequence gets block nblk,
+    out of range, which a write leaves out."""
+    nblk = table.shape[-1]
+    logical = jnp.clip(pos_b // bs, 0, nblk - 1)
+    blk = jnp.take_along_axis(table, logical[:, None], axis=1)[:, 0]
+    live = (pos_b >= 0) & (pos_b < nblk * bs)
+    return jnp.where(live, blk, nblk), pos_b % bs
+
+
+def attn_decode_in_place(params, cache, x, pos, cfg, layer, kv_backend):
+    """One-token paged decode on every layer's stacked cache (pool_k/pool_v
+    (layers, B, nblk, bs, nkv, hd), table (layers, B, nblk)), at `layer`:
+    the token's K/V are written into their block of the pool in place and
+    attention reads each sequence's live blocks through its table
+    (kernels/paged_attention, backend `kv_backend`).  Nothing of the pool is
+    sliced out or copied.  pos as in attn_decode: a free sequence (pos < 0)
+    writes nothing and reads nothing."""
+    B = x.shape[0]
+    bs = cfg.kv_block_size
+    pos_b = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,))
+    q, k_new, v_new = _project_qkv(params, x, cfg,
+                                   jnp.maximum(pos_b, 0)[:, None])
+    table = cache["table"][layer]                               # (B, nblk)
+    blk, row = _token_block(table, pos_b, bs)
+    pool_k, pool_v = paged_attention_ops.write_token(
+        cache["pool_k"], cache["pool_v"], k_new[:, 0], v_new[:, 0], blk, row,
+        layer, backend=kv_backend)
+    lengths = jnp.where(pos_b >= 0, pos_b + 1, 0)
+    o = paged_attention_ops.paged_decode_attention(
+        q[:, 0], pool_k, pool_v, table, lengths, layer, backend=kv_backend)
+    out = o.reshape(B, 1, -1).astype(x.dtype) @ params["wo_attn"]
+    return out, dict(cache, pool_k=pool_k, pool_v=pool_v)
+
+
 def attn_apply(params, x, cfg, rules, *, mode="train", cache=None, pos=None,
-               chunk=512):
-    """Unified entry. Returns (out, new_cache)."""
+               chunk=512, layer=None, kv_backend=None):
+    """Unified entry. Returns (out, new_cache).  Decode with `layer` given
+    works on every layer's stacked paged cache in place
+    (attn_decode_in_place)."""
     if mode == "decode":
+        if layer is not None:
+            return attn_decode_in_place(params, cache, x, pos, cfg, layer,
+                                        kv_backend)
         return attn_decode(params, cache, x, pos, cfg, rules)
     B, S, _ = x.shape
     positions = jnp.arange(S)[None]

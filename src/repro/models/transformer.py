@@ -104,13 +104,24 @@ def init_cache(cfg, batch: int, max_seq: int, layout: str = "dense") -> PyTree:
 
 
 # ------------------------------------------------------------------ apply
-def _block_apply(kind, p, x, cfg, rules, mode, cache, pos):
+def _block_apply(kind, p, x, cfg, rules, mode, cache, pos, layer=None,
+                 kv_backend=None):
+    """One block.  With `layer` given (decode in place), `cache` is the
+    stacked cache of every layer and comes back updated at `layer`."""
+    if layer is not None and kind not in (ATTN, ATTN_MOE):
+        x, nc = _block_apply(kind, p, x, cfg, rules, mode,
+                             jax.tree.map(lambda a: a[layer], cache), pos)
+        return x, jax.tree.map(
+            lambda a, u: jax.lax.dynamic_update_index_in_dim(
+                a, u.astype(a.dtype), layer, 0), cache, nc)
     eps = cfg.norm_eps
     h = rmsnorm(p["ln1"], x, eps)
     new_cache = None
     if kind in (ATTN, ATTN_MOE):
         a, new_cache = attention.attn_apply(p["attn"], h, cfg, rules,
-                                            mode=mode, cache=cache, pos=pos)
+                                            mode=mode, cache=cache, pos=pos,
+                                            layer=layer,
+                                            kv_backend=kv_backend)
         x = x + a
         h2 = rmsnorm(p["ln2"], x, eps)
         if kind == ATTN:
@@ -135,23 +146,81 @@ def _block_apply(kind, p, x, cfg, rules, mode, cache, pos):
     return x, new_cache
 
 
-def _shared_attn_apply(p, x, cfg, rules, mode, cache, pos):
+def _shared_attn_apply(p, x, cfg, rules, mode, cache, pos, layer=None,
+                       kv_backend=None):
     h = rmsnorm(p["ln"], x, cfg.norm_eps)
     a, new_cache = attention.attn_apply(p["attn"], h, cfg, rules,
-                                        mode=mode, cache=cache, pos=pos)
+                                        mode=mode, cache=cache, pos=pos,
+                                        layer=layer, kv_backend=kv_backend)
     x = x + a
     h2 = rmsnorm(p["ln2"], x, cfg.norm_eps)
     x = x + mlp(p["mlp"], h2, cfg)
     return x, new_cache
 
 
+def _paged(caches) -> bool:
+    return any("pool_k" in getattr(p[-1], "key", "") for p, _ in
+               jax.tree_util.tree_flatten_with_path(caches)[0])
+
+
+def _decode_in_place(params, x, cfg, caches, pos, kv_backend):
+    """The decode layer loop with every layer's caches as loop state: each
+    block reads and updates its layer of the stacked caches in place (the
+    paged KV pool through kernels/paged_attention), so no layer's cache is
+    sliced out as a scan input or stacked back as a scan output."""
+    unit = cfg.block_pattern
+
+    def run(x, p_layers, c_layers, first):
+        def body(carry, lp):
+            x, cs, i = carry
+            out = []
+            for pi, kind in enumerate(unit):
+                x, c = _block_apply(kind, lp[pi], x, cfg, None, "decode",
+                                    cs[pi], pos, layer=i,
+                                    kv_backend=kv_backend)
+                out.append(c)
+            return (x, tuple(out), i + 1), None
+
+        (x, c_layers, _), _ = jax.lax.scan(
+            body, (x, c_layers, jnp.int32(first)), p_layers)
+        return x, c_layers
+
+    every = cfg.shared_attn_every
+    if not every:
+        x, layers = run(x, params["layers"], caches["layers"], 0)
+        return x, {"layers": layers}
+    # zamba2-style: the groups unrolled as in forward; the shared block's
+    # caches are one stack indexed by the application
+    p_all = params["layers"][0]
+    layers, shared = caches["layers"], caches["shared"]
+    for g, start in enumerate(range(0, cfg.n_layers, every)):
+        sl = slice(start, min(start + every, cfg.n_layers))
+        x, layers = run(x, (jax.tree.map(lambda a: a[sl], p_all),), layers,
+                        start)
+        if sl.stop - sl.start == every:
+            x, shared = _shared_attn_apply(params["shared_attn"], x, cfg,
+                                           None, "decode", shared, pos,
+                                           layer=g, kv_backend=kv_backend)
+    return x, {"layers": layers, "shared": shared}
+
+
 def forward(params, inputs, cfg: ModelConfig, rules=None, *, mode="train",
-            caches=None, pos=None, return_hidden=False):
-    """inputs: (B,S) int tokens or (B,S,d) embeds.  Returns (logits, caches)."""
+            caches=None, pos=None, return_hidden=False, kv_backend=None):
+    """inputs: (B,S) int tokens or (B,S,d) embeds.  Returns (logits, caches).
+
+    Decode with `kv_backend` given (kernels/paged_attention's backends), on
+    a paged cache and unsharded (`rules` None), updates the caches in place
+    (_decode_in_place); otherwise decode slices each layer's cache and
+    gathers the pool in jnp."""
     x = embed(params["embed"], inputs, cfg)
     if rules is not None:
         x = rules.constrain(x, "batch",
                             "act_seq" if cfg.seq_shard else None, None)
+    if (mode == "decode" and kv_backend is not None and rules is None
+            and _paged(caches)):
+        x, new_caches = _decode_in_place(params, x, cfg, caches, pos,
+                                         kv_backend)
+        return _head(params, x, cfg, rules, new_caches, return_hidden)
     unit = cfg.block_pattern
     use_cache = caches is not None
     every = cfg.shared_attn_every
@@ -222,7 +291,10 @@ def forward(params, inputs, cfg: ModelConfig, rules=None, *, mode="train",
         x, layer_caches_out = seg_scan(
             x, params["layers"], caches["layers"] if use_cache else None)
         new_caches = {"layers": layer_caches_out} if use_cache else None
+    return _head(params, x, cfg, rules, new_caches, return_hidden)
 
+
+def _head(params, x, cfg, rules, new_caches, return_hidden):
     x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
     if return_hidden:
         return x, new_caches

@@ -11,7 +11,10 @@ old pool stays valid, so in-flight lookups never see a hole.
 
 Scheduler: admit-on-free-slot continuous batching; one engine `step()` =
 (admit+prefill new requests) + (one lockstep decode token for every active
-sequence, ragged positions via the per-seq `pos` vector).
+sequence, ragged positions via the per-seq `pos` vector, -1 for a free
+slot).  The decode step reads and writes the pool in place through
+kernels/paged_attention: each active sequence's live blocks, none of a free
+slot's.
 """
 from __future__ import annotations
 
@@ -47,7 +50,8 @@ class Request:
 class ServingEngine:
     def __init__(self, cfg: ModelConfig, params=None, *, max_slots: int = 4,
                  max_seq: int = 256, seed: int = 0, rules=None,
-                 scramble_blocks: bool = True):
+                 scramble_blocks: bool = True,
+                 kv_backend: Optional[str] = None):
         self.cfg = cfg
         self.max_slots = max_slots
         self.max_seq = max_seq
@@ -57,6 +61,10 @@ class ServingEngine:
         self.params = params if params is not None else init_params(
             jax.random.PRNGKey(seed), cfg)
         self.caches = init_cache(cfg, max_slots, max_seq, "paged")
+        # the decode step's paged-attention kernel: the compiled one where
+        # the pool lives on a TPU, the jnp oracle elsewhere; tests may force
+        # "pallas_interpret"
+        self.kv_backend = kv_backend or _kv_backend_for(self.caches)
         self.pos = np.zeros(max_slots, np.int64)
         self.active: Dict[int, Request] = {}
         self.queue: "collections.deque[Request]" = collections.deque()
@@ -69,7 +77,7 @@ class ServingEngine:
         def decode_fn(params, caches, tokens, pos):
             logits, new_caches = forward(params, tokens, cfg, rules,
                                          mode="decode", caches=caches,
-                                         pos=pos)
+                                         pos=pos, kv_backend=self.kv_backend)
             return jnp.argmax(logits[:, -1], axis=-1), new_caches
 
         self._decode = jax.jit(decode_fn, donate_argnums=(1,))
@@ -138,23 +146,32 @@ class ServingEngine:
 
     def step(self) -> int:
         """One engine iteration; returns number of tokens produced."""
-        with trace.span("serve.step"):
-            return self._step()
+        with trace.span("serve.step") as sid:
+            return self._step(sid)
 
-    def _step(self) -> int:
+    def _step(self, sid=None) -> int:
         self._admit()
         if not self.active:
             return 0
         tokens = np.zeros((self.max_slots, 1), np.int32)
+        pos = np.full(self.max_slots, -1, np.int32)
         for slot, req in self.active.items():
             tokens[slot, 0] = req.out[-1]
+            pos[slot] = self.pos[slot]
+        if sid is not None:
+            # per layer: the blocks the decode step's kernel reads (a
+            # sequence's ceil((pos + 1) / bs)), and the pool's
+            bs = self.cfg.kv_block_size
+            live = pos[pos >= 0].astype(np.int64)
+            trace.tag(sid, kv_blocks=int(np.sum(-(-(live + 1) // bs))),
+                      pool_blocks=self.max_slots * (self.max_seq // bs))
         if self.cfg.input_kind == "embeds":
             tok_in = jnp.zeros((self.max_slots, 1, self.cfg.d_model),
                                jnp.dtype(self.cfg.param_dtype))
         else:
             tok_in = jnp.asarray(tokens)
-        pos = jnp.asarray(np.maximum(self.pos, 0), jnp.int32)
-        nxt, self.caches = self._decode(self.params, self.caches, tok_in, pos)
+        nxt, self.caches = self._decode(self.params, self.caches, tok_in,
+                                        jnp.asarray(pos))
         nxt = np.asarray(nxt)
         produced = 0
         for slot in list(self.active):
@@ -201,6 +218,11 @@ class ServingEngine:
         with trace.span("serve.compact"):
             self.caches = compact_caches(self.caches, backend)
         self.compactions += 1
+
+
+def _kv_backend_for(caches) -> str:
+    device = next(iter(jax.tree.leaves(caches)[0].devices()))
+    return "pallas" if device.platform == "tpu" else "reference"
 
 
 def compact_caches(caches, backend: str):

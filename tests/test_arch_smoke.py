@@ -76,6 +76,39 @@ def test_prefill_decode_matches_forward(arch, layout):
                                rtol=3e-4, atol=3e-4)
 
 
+@pytest.mark.parametrize("arch", ARCH_IDS)
+@pytest.mark.parametrize("kv_backend", ["reference", "pallas_interpret"])
+def test_decode_in_place_matches_the_gathering_decode(arch, kv_backend):
+    """Decode on every layer's caches in place (kv_backend given) gives the
+    logits and caches of the decode that slices each layer out, for every
+    block kind that rides the loop (attention, MoE, Mamba2, xLSTM, Zamba2's
+    shared attention).  The pools are compared whole, a free sequence's
+    (pos -1) rows too; its recurrent state, fed by its unused hidden
+    state, is left out."""
+    cfg = get(arch, smoke=True).replace(param_dtype="float32",
+                                        kv_block_size=8)
+    params = init_params(KEY, cfg)
+    B, S = 3, 16
+    x, _ = _inputs(cfg, B, S, extra=1)
+    cache = init_cache(cfg, B, 32, "paged")
+    _, cache = forward(params, x[:, :S], cfg, mode="prefill", caches=cache)
+    pos = jnp.array([S, S - 3, -1], jnp.int32)
+    ref, ref_cache = forward(params, x[:, S:S + 1], cfg, mode="decode",
+                             caches=cache, pos=pos)
+    got, got_cache = forward(params, x[:, S:S + 1], cfg, mode="decode",
+                             caches=cache, pos=pos, kv_backend=kv_backend)
+    np.testing.assert_allclose(np.asarray(got[:2], np.float32),
+                               np.asarray(ref[:2], np.float32),
+                               rtol=1e-4, atol=1e-4)
+    flat = jax.tree_util.tree_flatten_with_path(ref_cache)[0]
+    for (path, b), a in zip(flat, jax.tree.leaves(got_cache)):
+        rows = slice(None) if "pool" in jax.tree_util.keystr(path) \
+            else slice(0, 2)                   # every leaf: (layers, B, ...)
+        np.testing.assert_allclose(np.asarray(a, np.float32)[:, rows],
+                                   np.asarray(b, np.float32)[:, rows],
+                                   rtol=1e-4, atol=1e-4)
+
+
 def test_decode_per_sequence_positions():
     """Ragged decode (continuous batching): per-seq pos vector must match
     per-seq scalar decode."""

@@ -14,7 +14,8 @@ except ImportError:  # container lacks hypothesis: seeded fallback
 from repro.kernels.flash_attention.ops import flash_attention
 from repro.kernels.kv_compaction.ops import compact_kv_pool
 from repro.kernels.kv_compaction.ref import compact_kv_pool_ref
-from repro.kernels.paged_attention.ops import paged_decode_attention
+from repro.kernels.paged_attention.ops import (paged_decode_attention,
+                                               write_token)
 
 KEY = jax.random.PRNGKey(0)
 
@@ -68,25 +69,80 @@ PAGED_SWEEP = [
     (1, 16, 8, 16, 8, 64, jnp.bfloat16),
     (4, 2, 2, 2, 64, 128, jnp.float32),
 ]
+LAYERS = 2
+
+
+def _paged_inputs(B, nh, nkv, nblk, bs, hd, dt, table="scrambled"):
+    """q, the stacked K/V pools (LAYERS, B, nblk, bs, nkv, hd) and a block
+    table, scrambled or the identity."""
+    ks = jax.random.split(KEY, 4)
+    q = jax.random.normal(ks[0], (B, nh, hd), dt)
+    pk = jax.random.normal(ks[1], (LAYERS, B, nblk, bs, nkv, hd), dt)
+    pv = jax.random.normal(ks[2], (LAYERS, B, nblk, bs, nkv, hd), dt)
+    if table == "identity":
+        tbl = jnp.tile(jnp.arange(nblk, dtype=jnp.int32)[None], (B, 1))
+    else:
+        tbl = jnp.stack([
+            jax.random.permutation(jax.random.fold_in(ks[3], b), nblk)
+            for b in range(B)]).astype(jnp.int32)
+    return q, pk, pv, tbl
+
+
+def _assert_kernel_matches_oracle(q, pk, pv, table, lengths, layer, dt):
+    ref = paged_decode_attention(q, pk, pv, table, lengths, layer,
+                                 backend="reference")
+    out = paged_decode_attention(q, pk, pv, table, lengths, layer,
+                                 backend="pallas_interpret")
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref, np.float32), **tol(dt))
+    return out
 
 
 @pytest.mark.parametrize("B,nh,nkv,nblk,bs,hd,dt", PAGED_SWEEP)
 def test_paged_attention_sweep(B, nh, nkv, nblk, bs, hd, dt):
-    ks = jax.random.split(KEY, 4)
-    q = jax.random.normal(ks[0], (B, nh, hd), dt)
-    pk = jax.random.normal(ks[1], (B, nblk, bs, nkv, hd), dt)
-    pv = jax.random.normal(ks[2], (B, nblk, bs, nkv, hd), dt)
-    table = jnp.stack([
-        jax.random.permutation(jax.random.fold_in(ks[3], b), nblk)
-        for b in range(B)]).astype(jnp.int32)
+    q, pk, pv, table = _paged_inputs(B, nh, nkv, nblk, bs, hd, dt)
     length = jnp.array([max(1, nblk * bs - 5)] + [nblk * bs] * (B - 1),
                        jnp.int32)
-    ref = paged_decode_attention(q, pk, pv, table, length,
-                                 backend="reference")
-    out = paged_decode_attention(q, pk, pv, table, length,
-                                 backend="pallas_interpret")
-    np.testing.assert_allclose(np.asarray(out, np.float32),
-                               np.asarray(ref, np.float32), **tol(dt))
+    _assert_kernel_matches_oracle(q, pk, pv, table, length, 1, dt)
+
+
+# the serving cell's heads (Qwen3-8B): 32 query heads over 8 KV heads of
+# 128, blocks of 64 tokens, bf16; lengths ragged: empty, one token, a block
+# boundary either side, and full
+CELL_HEADS = dict(nh=32, nkv=8, hd=128, bs=64, dt=jnp.bfloat16)
+CELL_LENGTHS = [0, 1, 64, 65, 128, 4 * 64]
+
+
+@pytest.mark.parametrize("layer", range(LAYERS))
+@pytest.mark.parametrize("table", ["scrambled", "identity"])
+def test_paged_attention_at_the_cells_heads(layer, table):
+    B, nblk = len(CELL_LENGTHS), 4
+    q, pk, pv, tbl = _paged_inputs(B, nblk=nblk, table=table, **CELL_HEADS)
+    out = _assert_kernel_matches_oracle(
+        q, pk, pv, tbl, jnp.array(CELL_LENGTHS, jnp.int32), layer,
+        CELL_HEADS["dt"])
+    assert not np.any(np.asarray(out[0], np.float32))   # length 0: zeros
+
+
+@pytest.mark.parametrize("backend", ["pallas_interpret", "reference"])
+def test_token_write_touches_only_its_rows(backend):
+    """Each live sequence's K/V row lands at (layer, b, blk, row) and
+    nothing else of the pool changes; a block out of range writes
+    nothing."""
+    B, nkv, nblk, bs, hd = 4, 2, 4, 8, 128
+    _, pk, pv, table = _paged_inputs(B, 4, nkv, nblk, bs, hd, jnp.float32)
+    k_new = jnp.full((B, nkv, hd), 7.0)
+    v_new = -k_new
+    blk = table[:, 2].at[1].set(nblk)
+    row = jnp.array([0, 5, bs - 1, 3], jnp.int32)
+    nk, nv = write_token(pk, pv, k_new, v_new, blk, row, 1, backend=backend)
+    want_k, want_v = np.array(pk), np.array(pv)
+    for b, (k, r) in enumerate(zip(np.asarray(blk), np.asarray(row))):
+        if k < nblk:
+            want_k[1, b, k, r] = 7.0
+            want_v[1, b, k, r] = -7.0
+    assert np.array_equal(np.asarray(nk), want_k)
+    assert np.array_equal(np.asarray(nv), want_v)
 
 
 @settings(max_examples=20, deadline=None,
@@ -112,26 +168,29 @@ def test_compaction_is_permutation_inverse(B, nblk, bs, C, seed):
 def test_paged_attention_invariant_under_compaction():
     """Attention(q, pool, table) == Attention(q, compact(pool), identity) —
     the kernel-level statement of the paper's GC correctness."""
-    ks = jax.random.split(KEY, 4)
     B, nh, nkv, nblk, bs, hd = 2, 4, 2, 8, 16, 64
-    q = jax.random.normal(ks[0], (B, nh, hd), jnp.float32)
-    pk = jax.random.normal(ks[1], (B, nblk, bs, nkv, hd), jnp.float32)
-    pv = jax.random.normal(ks[2], (B, nblk, bs, nkv, hd), jnp.float32)
-    table = jnp.stack([
-        jax.random.permutation(jax.random.fold_in(ks[3], b), nblk)
-        for b in range(B)]).astype(jnp.int32)
+    q, pk, pv, table = _paged_inputs(B, nh, nkv, nblk, bs, hd, jnp.float32)
     length = jnp.full((B,), nblk * bs, jnp.int32)
-    before = paged_decode_attention(q, pk, pv, table, length,
+    before = paged_decode_attention(q, pk, pv, table, length, 1,
                                     backend="pallas_interpret")
-    ck, ident = compact_kv_pool(pk.reshape(B, nblk, bs, -1), table,
+    flat = (LAYERS * B, nblk, bs, nkv * hd)
+    flat_t = jnp.tile(table, (LAYERS, 1))
+    ck, ident = compact_kv_pool(pk.reshape(flat), flat_t,
                                 backend="pallas_interpret")
-    cv, _ = compact_kv_pool(pv.reshape(B, nblk, bs, -1), table,
+    cv, _ = compact_kv_pool(pv.reshape(flat), flat_t,
                             backend="pallas_interpret")
     after = paged_decode_attention(
-        q, ck.reshape(pk.shape), cv.reshape(pv.shape), ident, length,
+        q, ck.reshape(pk.shape), cv.reshape(pv.shape), ident[:B], length, 1,
         backend="pallas_interpret")
     np.testing.assert_allclose(np.asarray(before), np.asarray(after),
                                rtol=1e-6, atol=1e-6)
+
+
+def test_paged_attention_rejects_an_unknown_backend():
+    q, pk, pv, table = _paged_inputs(1, 2, 2, 2, 8, 128, jnp.float32)
+    with pytest.raises(ValueError, match="not one of"):
+        paged_decode_attention(q, pk, pv, table, jnp.ones((1,), jnp.int32),
+                               0, backend="pallas_compiled")
 
 
 def test_compaction_rejects_an_unknown_backend():

@@ -72,3 +72,57 @@ def test_mid_stream_admission():
     eng.run_until_drained()
     assert r1.out[:8] == ref_generate([7, 7, 7], 8)[:8]
     assert r2.out[:4] == ref_generate([1, 2, 3, 4], 4)[:4]
+
+
+def test_the_engine_picks_the_kernel_from_where_its_pool_lives():
+    eng = ServingEngine(CFG, PARAMS, max_slots=2, max_seq=32, seed=0)
+    assert eng.kv_backend == "reference"           # the pool is on the CPU
+
+
+def test_pallas_interpret_engine_matches_the_reference_engine():
+    """The decode step's kernels, interpreted, serve the same tokens as the
+    jnp oracles, across admissions mid-stream and a compaction."""
+    def serve(backend):
+        eng = ServingEngine(CFG, PARAMS, max_slots=3, max_seq=64, seed=5,
+                            scramble_blocks=True, kv_backend=backend)
+        reqs = [eng.submit(p, max_new=8) for p in ([5, 9, 2, 7], [1, 2, 3])]
+        for _ in range(3):
+            eng.step()
+        reqs += [eng.submit(p, max_new=6)
+                 for p in ([11, 4, 6, 8, 10], [3, 3, 3])]
+        for _ in range(2):
+            eng.step()
+        eng.compact(backend="reference")
+        eng.run_until_drained()
+        return [r.out for r in reqs]
+
+    assert serve("pallas_interpret") == serve("reference")
+
+
+@pytest.mark.parametrize("backend", ["reference", "pallas_interpret"])
+def test_a_decode_step_writes_only_the_active_rows(backend):
+    """One decode step changes each layer's pool at the active sequence's
+    new-token row and nowhere else: a free slot writes nothing, also one
+    whose stale pos is max_seq."""
+    eng = ServingEngine(CFG, PARAMS, max_slots=3, max_seq=32, seed=4,
+                        kv_backend=backend)
+    eng.submit(list(range(1, 31)), max_new=2)      # ends with pos == max_seq
+    live = eng.submit([3, 1, 4], max_new=10)
+    while not eng.finished:
+        eng.step()
+    assert eng.max_seq in [eng.pos[s] for s in eng.free_slots]
+    # a constant pool: any write shows
+    eng.caches = jax.tree.map(
+        lambda a: jnp.full_like(a, 1e4) if a.dtype != jnp.int32 else a,
+        eng.caches)
+    before = jax.tree.map(np.asarray, eng.caches)
+    pos, bs = int(eng.pos[live.slot]), CFG.kv_block_size
+    eng.step()
+    for old, new in zip(before["layers"], eng.caches["layers"]):
+        blk = old["table"][:, live.slot, pos // bs]
+        want = {(l, live.slot, int(blk[l]), pos % bs)
+                for l in range(len(blk))}
+        for name in ("pool_k", "pool_v"):
+            changed = np.argwhere(np.any(old[name] != np.asarray(new[name]),
+                                         axis=(-2, -1)))
+            assert {tuple(int(i) for i in c) for c in changed} == want

@@ -1,7 +1,8 @@
-"""The kv_compaction kernel at SmolLM-135M serving widths, compiled for a
-described TPU v5e (nothing runs): the chip's compiler accepts the compiled
-path `compact_kv_pool(backend="pallas")`, the program holds the Pallas
-custom call, and it fits one chip's HBM.
+"""The serving path's kernels compiled for a described TPU v5e (nothing
+runs): the kv_compaction kernel at SmolLM-135M serving widths (the chip's
+compiler accepts `compact_kv_pool(backend="pallas")`, the program holds the
+Pallas custom call, and it fits one chip's HBM), and the paged decode step
+at the serving cell's attention widths, in place.
 
 The topology is described only inside fixtures: describing it loads the TPU
 compiler library, which one process at a time may hold."""
@@ -15,6 +16,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get
 from repro.kernels.kv_compaction.ops import compact_kv_pool
+from repro.models import forward, init_cache, init_params
 
 CFG = get("smollm_135m")
 SLOTS, MAX_SEQ = 32, 2048          # chip_smoke.py's ServingEngine widths
@@ -91,3 +93,50 @@ def test_compaction_compiles_for_v5e_with_runtime_table(pool, one_chip):
                                  sharding=one_chip)
     compiled = compact_kv_pool.lower(pool, table, backend="pallas").compile()
     _assert_fits_and_uses_kernel(compiled)
+
+
+# the serving cell's attention widths (Qwen3-8B: 32 query heads over 8 KV
+# heads of 128, blocks of 64) in 2 layers, 32 slots of 2,048 tokens
+SERVE_CFG = get("qwen3_8b").replace(n_layers=2, vocab_size=37984,
+                                    kv_block_size=64)
+LAYER_POOL_BYTES = (SLOTS * MAX_SEQ * SERVE_CFG.n_kv_heads * SERVE_CFG.hd
+                    * 2)                # one layer's K pool in bf16: 134 MB
+
+
+def _decode_step(one_chip, **kw):
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda: init_params(jax.random.PRNGKey(0), SERVE_CFG)))
+    caches = on_chip(jax.eval_shape(
+        lambda: init_cache(SERVE_CFG, SLOTS, MAX_SEQ, "paged")))
+
+    def step(params, caches, tokens, pos):     # ServingEngine's decode_fn
+        logits, caches = forward(params, tokens, SERVE_CFG, None,
+                                 mode="decode", caches=caches, pos=pos, **kw)
+        return jnp.argmax(logits[:, -1], axis=-1), caches
+
+    tokens = jax.ShapeDtypeStruct((SLOTS, 1), jnp.int32, sharding=one_chip)
+    pos = jax.ShapeDtypeStruct((SLOTS,), jnp.int32, sharding=one_chip)
+    return jax.jit(step, donate_argnums=(1,)).lower(params, caches, tokens,
+                                                   pos).compile()
+
+
+def test_paged_decode_step_compiles_in_place_for_v5e(one_chip):
+    """The engine's decode step on the chip: the paged-attention kernel is
+    in it, and its temporaries stay under one layer's K pool, where the
+    step that gathers the whole pool every layer (forward with no
+    kv_backend, the next test; the serving engine's step before the
+    in-place kernels) needs 1,646,139,904 B of them."""
+    compiled = _decode_step(one_chip, kv_backend="pallas")
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "paged_decode_attention" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < LAYER_POOL_BYTES
+
+
+def test_the_gathering_decode_step_holds_more_than_a_layers_pool(one_chip):
+    compiled = _decode_step(one_chip)
+    assert "paged_decode_attention" not in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes > LAYER_POOL_BYTES
