@@ -15,6 +15,7 @@ from typing import Optional, Tuple
 ATTN = "attn"          # GQA attention + MLP (dense transformer layer)
 ATTN_MOE = "attn_moe"  # GQA attention + MoE FFN
 MAMBA2 = "mamba2"      # Mamba-2 (SSD) block
+MAMBA2_MOE = "mamba2_moe"  # Mamba-2 mixer + MoE FFN (granite-4.0-h)
 MLSTM = "mlstm"        # xLSTM matrix-memory block
 SLSTM = "slstm"        # xLSTM scalar-memory block
 
@@ -34,11 +35,23 @@ class ModelConfig:
     qk_norm: bool = False            # qwen3 / chameleon
     qkv_bias: bool = False           # qwen2
     rope_theta: float = 10_000.0
+    nope: bool = False               # no positional encoding (granite-4.0-h)
+    attn_scale: Optional[float] = None   # softmax scale; None: hd ** -0.5
     mlp_kind: str = "swiglu"         # swiglu | gelu
     # MoE
     n_experts: int = 0
     top_k: int = 0
     capacity_factor: float = 1.25
+    # expert parallelism's share: the router scores all n_experts, and this
+    # chip holds experts [expert_first, expert_first + experts_held) and
+    # computes their part of the result (0 held: all of them)
+    experts_held: int = 0
+    expert_first: int = 0
+    shared_expert_ff: int = 0        # a SwiGLU expert every token takes
+    # granite's scalars: embeddings x, each residual branch x, logits /
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
     # layer pattern: repeating unit of block kinds, cycled to n_layers
     block_pattern: Tuple[str, ...] = (ATTN,)
     # zamba2-style shared attention block applied every k-th layer (0 = off)
@@ -86,6 +99,11 @@ class ModelConfig:
     def is_moe(self) -> bool:
         return self.n_experts > 0
 
+    @property
+    def n_held(self) -> int:
+        """Experts whose weights live here."""
+        return self.experts_held or self.n_experts
+
     def pattern_for_layers(self) -> Tuple[str, ...]:
         """Full per-layer block kinds (len == n_layers)."""
         unit = self.block_pattern
@@ -124,7 +142,8 @@ class ModelConfig:
         if self.qkv_bias:
             attn += (nh + 2 * nkv) * hd
         mlp = (3 if self.mlp_kind == "swiglu" else 2) * d * ff
-        moe = self.n_experts * (3 * d * ff) + d * self.n_experts if self.is_moe else 0
+        moe = (self.n_held * (3 * d * ff) + d * self.n_experts
+               + 3 * d * self.shared_expert_ff) if self.is_moe else 0
         di = self.ssm_expand * d
         nh_ssm = max(di // self.ssm_head_dim, 1)
         mamba = (d * (2 * di + 2 * self.ssm_state + nh_ssm)  # in_proj(z,x)+B,C+dt
@@ -137,6 +156,8 @@ class ModelConfig:
                 total += attn + moe
             elif kind == MAMBA2:
                 total += mamba
+            elif kind == MAMBA2_MOE:
+                total += mamba + moe
             elif kind == MLSTM:
                 di_m = 2 * d  # up-projection factor 2
                 # in: d->2*di (x and gate z); qkv: 3 projections di->di; out di->d
@@ -149,12 +170,15 @@ class ModelConfig:
         return int(total)
 
     def active_param_count(self) -> int:
-        """Params touched per token (MoE: top_k of n_experts)."""
+        """Params touched per token (MoE: top_k of n_experts, or of the held
+        share the experts a token routes here on average)."""
         if not self.is_moe:
             return self.param_count()
         d, ff = self.d_model, self.d_ff
-        dead = self.n_experts * 3 * d * ff - self.top_k * 3 * d * ff
-        n_moe_layers = sum(1 for k in self.pattern_for_layers() if k == ATTN_MOE)
+        used = self.top_k * self.n_held / self.n_experts
+        dead = (self.n_held - used) * 3 * d * ff
+        n_moe_layers = sum(1 for k in self.pattern_for_layers()
+                           if k in (ATTN_MOE, MAMBA2_MOE))
         return int(self.param_count() - dead * n_moe_layers)
 
 
@@ -176,6 +200,7 @@ SHAPES = {
 ARCH_IDS = [
     "smollm_135m", "deepseek_7b", "qwen2_72b", "qwen3_8b", "musicgen_medium",
     "chameleon_34b", "zamba2_1p2b", "olmoe_1b_7b", "dbrx_132b", "xlstm_125m",
+    "granite_4_0_h_small",
 ]
 
 

@@ -136,10 +136,11 @@ def _next_live(lengths):
 
 
 def paged_decode_attention_pallas(q, pool_k, pool_v, table, lengths, layer,
-                                  *, interpret: bool = False):
+                                  *, scale=None, interpret: bool = False):
     """q: (B, nh, hd); pool_k/v: (layers, B, nblk, bs, nkv, hd); table:
     (B, nblk) int32, logical -> physical block of `layer`; lengths: (B,)
-    int32 valid tokens per sequence; layer: int32 scalar.  Returns
+    int32 valid tokens per sequence; layer: int32 scalar; scale: the
+    softmax's (a compile-time constant), hd ** -0.5 where None.  Returns
     (B, nh, hd); a sequence of length 0 gives zeros."""
     B, nh, hd = q.shape
     nblk, bs, nkv = pool_k.shape[2], pool_k.shape[3], pool_k.shape[4]
@@ -147,7 +148,7 @@ def paged_decode_attention_pallas(q, pool_k, pool_v, table, lengths, layer,
     scalars = (jnp.reshape(jnp.asarray(layer, jnp.int32), (1,)), lengths,
                table.reshape(-1).astype(jnp.int32), _next_live(lengths))
     kernel = functools.partial(_attn_kernel, bs=bs, nblk=nblk, nkv=nkv,
-                               scale=hd ** -0.5)
+                               scale=hd ** -0.5 if scale is None else scale)
     seq = lambda b, *_: (b, 0, 0)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalars),

@@ -2,6 +2,7 @@
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 
@@ -20,15 +21,16 @@ def _check(backend: str) -> None:
         raise ValueError(f"backend {backend!r} is not one of {BACKENDS}")
 
 
-@functools.partial(jax.jit, static_argnames=("backend",))
+@functools.partial(jax.jit, static_argnames=("backend", "scale"))
 def paged_decode_attention(q, pool_k, pool_v, table, lengths, layer, *,
-                           backend: str):
+                           backend: str, scale: Optional[float] = None):
+    """Softmax scale `scale`, hd ** -0.5 where None."""
     _check(backend)
     if backend == "reference":
         return paged_decode_attention_ref(q, pool_k, pool_v, table, lengths,
-                                          layer)
+                                          layer, scale)
     return paged_decode_attention_pallas(
-        q, pool_k, pool_v, table, lengths, layer,
+        q, pool_k, pool_v, table, lengths, layer, scale=scale,
         interpret=(backend == "pallas_interpret"))
 
 

@@ -7,11 +7,13 @@ import jax.numpy as jnp
 NEG_INF = -1e30
 
 
-def paged_decode_attention_ref(q, pool_k, pool_v, table, lengths, layer):
+def paged_decode_attention_ref(q, pool_k, pool_v, table, lengths, layer,
+                               scale=None):
     """q: (B, nh, hd); pool_k/v: (layers, B, nblk, bs, nkv, hd); table:
     (B, nblk) int32 (logical block -> physical block of `layer`); lengths:
-    scalar or (B,) number of valid tokens.  Returns (B, nh, hd); a sequence
-    of length 0 gives zeros."""
+    scalar or (B,) number of valid tokens; scale: the softmax's, hd ** -0.5
+    where None.  Returns (B, nh, hd); a sequence of length 0 gives
+    zeros."""
     B, nh, hd = q.shape
     nblk, bs, nkv = pool_k.shape[2], pool_k.shape[3], pool_k.shape[4]
     rep = nh // nkv
@@ -21,7 +23,8 @@ def paged_decode_attention_ref(q, pool_k, pool_v, table, lengths, layer):
     v = jnp.take_along_axis(pool_v[layer], tbl, axis=1).reshape(
         B, nblk * bs, nkv, hd)
     qf = q.astype(jnp.float32).reshape(B, nkv, rep, hd)
-    s = jnp.einsum("bgrd,bkgd->bgrk", qf, k.astype(jnp.float32)) * hd ** -0.5
+    s = jnp.einsum("bgrd,bkgd->bgrk", qf, k.astype(jnp.float32)) * (
+        hd ** -0.5 if scale is None else scale)
     lengths = jnp.broadcast_to(jnp.asarray(lengths), (B,))
     mask = jnp.arange(nblk * bs)[None] < lengths[:, None]      # (B, S)
     s = jnp.where(mask[:, None, None], s, NEG_INF)

@@ -44,6 +44,10 @@ def attn_init(key, cfg):
     return p
 
 
+def softmax_scale(cfg) -> float:
+    return cfg.attn_scale if cfg.attn_scale is not None else cfg.hd ** -0.5
+
+
 def _project_qkv(params, x, cfg, positions):
     B, S, _ = x.shape
     nh, nkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
@@ -60,8 +64,9 @@ def _project_qkv(params, x, cfg, positions):
     if cfg.qk_norm:
         q = rmsnorm({"scale": params["q_norm_scale"]}, q, cfg.norm_eps)
         k = rmsnorm({"scale": params["k_norm_scale"]}, k, cfg.norm_eps)
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
+    if not cfg.nope:
+        q = apply_rope(q, positions, cfg.rope_theta)
+        k = apply_rope(k, positions, cfg.rope_theta)
     return q, k, v
 
 
@@ -93,12 +98,14 @@ def _sdpa_chunk(qc, k, v, q_pos, kv_pos, scale):
     return o
 
 
-def chunked_causal_attention(q, k, v, *, q_offset=0, chunk=512, rules=None):
-    """q: (B,Sq,nh,hd); k,v: (B,Skv,nkv,hd). Returns (B,Sq,nh,hd)."""
+def chunked_causal_attention(q, k, v, *, q_offset=0, chunk=512, rules=None,
+                             scale=None):
+    """q: (B,Sq,nh,hd); k,v: (B,Skv,nkv,hd). Returns (B,Sq,nh,hd); the
+    softmax scale defaults to hd ** -0.5."""
     B, Sq, nh, hd = q.shape
     Skv, nkv = k.shape[1], k.shape[2]
     rep = nh // nkv
-    scale = hd ** -0.5
+    scale = hd ** -0.5 if scale is None else scale
     k = _repeat_kv(k, rep, rules)
     v = _repeat_kv(v, rep, rules)
     kv_pos = jnp.arange(Skv)
@@ -141,7 +148,7 @@ def init_attn_cache(cfg, batch: int, max_seq: int, layout: str, dtype=None):
     }
 
 
-def _decode_attend(q, k_all, v_all, pos, nh, rules):
+def _decode_attend(q, k_all, v_all, pos, nh, rules, scale):
     """q:(B,1,nh,hd) vs full cache (B,S,nkv,hd) masked to <=pos.
 
     Grouped (no KV repeat): the cache is read once — decode is HBM-bound and
@@ -151,7 +158,6 @@ def _decode_attend(q, k_all, v_all, pos, nh, rules):
     sharded S lower to the flash-decoding split-K all-reduce pattern."""
     B, S, nkv, hd = k_all.shape
     rep = nh // nkv
-    scale = hd ** -0.5
     qg = q.reshape(B, 1, nkv, rep, hd)
     s = jnp.einsum("bqgrd,bkgd->bgrqk", qg.astype(jnp.float32),
                    k_all.astype(jnp.float32)) * scale
@@ -199,7 +205,7 @@ def attn_decode(params, cache, x, pos, cfg, rules):
         if rules is not None:
             k_all = rules.constrain(k_all, "batch", "cache_seq")
             v_all = rules.constrain(v_all, "batch", "cache_seq")
-    o = _decode_attend(q, k_all, v_all, pos, nh, rules)
+    o = _decode_attend(q, k_all, v_all, pos, nh, rules, softmax_scale(cfg))
     out = o.astype(x.dtype) @ params["wo_attn"]
     return out, new_cache
 
@@ -235,7 +241,8 @@ def attn_decode_in_place(params, cache, x, pos, cfg, layer, kv_backend):
         layer, backend=kv_backend)
     lengths = jnp.where(pos_b >= 0, pos_b + 1, 0)
     o = paged_attention_ops.paged_decode_attention(
-        q[:, 0], pool_k, pool_v, table, lengths, layer, backend=kv_backend)
+        q[:, 0], pool_k, pool_v, table, lengths, layer, backend=kv_backend,
+        scale=cfg.attn_scale)
     out = o.reshape(B, 1, -1).astype(x.dtype) @ params["wo_attn"]
     return out, dict(cache, pool_k=pool_k, pool_v=pool_v)
 
@@ -262,14 +269,15 @@ def attn_apply(params, x, cfg, rules, *, mode="train", cache=None, pos=None,
         v = rules.constrain(v, "batch", None, None, None)
         rep = cfg.n_heads // cfg.n_kv_heads
         o = _sdpa_chunk(q, _repeat_kv(k, rep), _repeat_kv(v, rep),
-                        jnp.arange(S), jnp.arange(S), cfg.hd ** -0.5)
+                        jnp.arange(S), jnp.arange(S), softmax_scale(cfg))
         o = o.astype(q.dtype)
     else:
         if rules is not None:
             q = rules.constrain(q, "batch", None, "heads")
             k = rules.constrain(k, "batch", None, "kv_heads")
             v = rules.constrain(v, "batch", None, "kv_heads")
-        o = chunked_causal_attention(q, k, v, chunk=chunk, rules=rules)
+        o = chunked_causal_attention(q, k, v, chunk=chunk, rules=rules,
+                                     scale=softmax_scale(cfg))
     out = o.reshape(B, S, -1) @ params["wo_attn"]
     new_cache = None
     if mode == "prefill" and cache is not None:

@@ -81,8 +81,12 @@ def embed_init(key, cfg):
     dt = _dtype(cfg)
     p: Dict[str, Any] = {}
     if cfg.input_kind == "tokens":
+        # drawn at 1/embedding_multiplier of the usual scale, so that the
+        # scaled embedding starts at it (a tied head then does not favour
+        # the input token by the multiplier)
         p["embedding"] = dense_init(key, (cfg.vocab_size, cfg.d_model), dt,
-                                    fan_in=cfg.d_model)
+                                    fan_in=cfg.d_model
+                                    * cfg.embedding_multiplier ** 2)
     if not cfg.tie_embeddings:
         p["unembed"] = dense_init(jax.random.fold_in(key, 1),
                                   (cfg.d_model, cfg.vocab_size), dt)

@@ -93,6 +93,16 @@ def ssd_chunked(x, dt, A, Bm, Cm, chunk: int, init_state=None):
     return y.astype(x.dtype), S_final
 
 
+def _conv_tail(xBC, K, lengths):
+    """(B, K-1, C): the K-1 conv inputs before each sequence's length (the
+    last K-1 where lengths is None), zeros before the sequence's start."""
+    pads = jnp.pad(xBC, ((0, 0), (K - 1, 0), (0, 0)))
+    if lengths is None:
+        return pads[:, -(K - 1):]
+    return jax.vmap(lambda p, n: jax.lax.dynamic_slice_in_dim(
+        p, n, K - 1, axis=0))(pads, lengths)
+
+
 def init_mamba_cache(cfg, batch: int, dtype=None):
     di, nh, N, K = _dims(cfg)
     dt = dtype or jnp.dtype(cfg.param_dtype)
@@ -102,8 +112,12 @@ def init_mamba_cache(cfg, batch: int, dtype=None):
     }
 
 
-def mamba2_apply(params, x, cfg, rules, *, mode="train", cache=None, pos=None):
-    """x: (B,S,d) (train/prefill) or (B,1,d) (decode)."""
+def mamba2_apply(params, x, cfg, rules, *, mode="train", cache=None, pos=None,
+                 lengths=None):
+    """x: (B,S,d) (train/prefill) or (B,1,d) (decode).  A prefill padded
+    past each sequence's `lengths` (B,) leaves the state and conv tail at
+    the length: dt is 0 past it, and the tail is the K-1 inputs before
+    it."""
     di, nh, N, K = _dims(cfg)
     hp = cfg.ssm_head_dim
     B = x.shape[0]
@@ -133,6 +147,9 @@ def mamba2_apply(params, x, cfg, rules, *, mode="train", cache=None, pos=None):
         Bm = xBC_c[..., di:di + N]
         Cm = xBC_c[..., di + N:]
         dtv = jax.nn.softplus(dt_raw.astype(jnp.float32) + params["dt_bias"])
+        if lengths is not None:
+            live = jnp.arange(x.shape[1])[None, :] < lengths[:, None]
+            dtv = jnp.where(live[..., None], dtv, 0.0)
         if rules is not None:
             xs = rules.constrain(xs, "batch", None, "heads")
         y, S_final = ssd_chunked(xs, dtv, A, Bm, Cm, chunk=128)
@@ -140,6 +157,6 @@ def mamba2_apply(params, x, cfg, rules, *, mode="train", cache=None, pos=None):
         y = y.reshape(B, x.shape[1], di).astype(x.dtype)
         new_cache = None
         if mode == "prefill" and cache is not None:
-            new_cache = {"ssm": S_final, "conv": xBC[:, -(K - 1):]}
+            new_cache = {"ssm": S_final, "conv": _conv_tail(xBC, K, lengths)}
     y = rmsnorm({"scale": params["norm_scale"]}, y * jax.nn.silu(z), cfg.norm_eps)
     return y @ params["out_proj"], new_cache

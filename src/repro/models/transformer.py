@@ -16,7 +16,8 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.configs.base import ATTN, ATTN_MOE, MAMBA2, MLSTM, SLSTM, ModelConfig
+from repro.configs.base import (ATTN, ATTN_MOE, MAMBA2, MAMBA2_MOE, MLSTM,
+                                SLSTM, ModelConfig)
 from repro.models import attention, mamba2, moe as moe_mod, xlstm
 from repro.models.layers import embed, embed_init, mlp, mlp_init, rmsnorm, \
     rmsnorm_init, unembed
@@ -35,8 +36,11 @@ def _block_init(key, cfg, kind):
             p["mlp"] = mlp_init(ks[1], cfg)
         else:
             p["moe"] = moe_mod.moe_init(ks[1], cfg)
-    elif kind == MAMBA2:
+    elif kind in (MAMBA2, MAMBA2_MOE):
         p["mamba"] = mamba2.mamba2_init(ks[0], cfg)
+        if kind == MAMBA2_MOE:
+            p["ln2"] = rmsnorm_init(cfg.d_model, jnp.dtype(cfg.param_dtype))
+            p["moe"] = moe_mod.moe_init(ks[1], cfg)
     elif kind == MLSTM:
         p["mlstm"] = xlstm.mlstm_init(ks[0], cfg)
     elif kind == SLSTM:
@@ -80,7 +84,7 @@ def init_params(key, cfg: ModelConfig) -> PyTree:
 def _block_cache(cfg, kind, batch, max_seq, layout):
     if kind in (ATTN, ATTN_MOE):
         return attention.init_attn_cache(cfg, batch, max_seq, layout)
-    if kind == MAMBA2:
+    if kind in (MAMBA2, MAMBA2_MOE):
         return mamba2.init_mamba_cache(cfg, batch)
     if kind == MLSTM:
         return xlstm.init_mlstm_cache(cfg, batch)
@@ -104,34 +108,44 @@ def init_cache(cfg, batch: int, max_seq: int, layout: str = "dense") -> PyTree:
 
 
 # ------------------------------------------------------------------ apply
+def _residual(x, y, cfg):
+    """x + the branch y, scaled by the residual multiplier where it has
+    one."""
+    if cfg.residual_multiplier != 1.0:
+        y = y * jnp.asarray(cfg.residual_multiplier, y.dtype)
+    return x + y
+
+
 def _block_apply(kind, p, x, cfg, rules, mode, cache, pos, layer=None,
-                 kv_backend=None):
-    """One block.  With `layer` given (decode in place), `cache` is the
-    stacked cache of every layer and comes back updated at `layer`."""
+                 kv_backend=None, lengths=None, count_mask=None):
+    """One block; returns (x, new_cache, counts).  With `layer` given
+    (decode in place), `cache` is the stacked cache of every layer and
+    comes back updated at `layer`.  `lengths` (B,): a padded prefill's
+    sequence lengths, for the recurrent state.  With `count_mask` given,
+    an MoE block's counts are moe.route_counts of the tokens under it;
+    otherwise counts is None."""
     if layer is not None and kind not in (ATTN, ATTN_MOE):
-        x, nc = _block_apply(kind, p, x, cfg, rules, mode,
-                             jax.tree.map(lambda a: a[layer], cache), pos)
+        x, nc, counts = _block_apply(
+            kind, p, x, cfg, rules, mode,
+            jax.tree.map(lambda a: a[layer], cache), pos,
+            count_mask=count_mask)
         return x, jax.tree.map(
             lambda a, u: jax.lax.dynamic_update_index_in_dim(
-                a, u.astype(a.dtype), layer, 0), cache, nc)
+                a, u.astype(a.dtype), layer, 0), cache, nc), counts
     eps = cfg.norm_eps
     h = rmsnorm(p["ln1"], x, eps)
-    new_cache = None
+    new_cache = counts = None
     if kind in (ATTN, ATTN_MOE):
         a, new_cache = attention.attn_apply(p["attn"], h, cfg, rules,
                                             mode=mode, cache=cache, pos=pos,
                                             layer=layer,
                                             kv_backend=kv_backend)
-        x = x + a
-        h2 = rmsnorm(p["ln2"], x, eps)
-        if kind == ATTN:
-            x = x + mlp(p["mlp"], h2, cfg)
-        else:
-            x = x + moe_mod.moe_apply(p["moe"], h2, cfg, rules)
-    elif kind == MAMBA2:
+        x = _residual(x, a, cfg)
+    elif kind in (MAMBA2, MAMBA2_MOE):
         y, new_cache = mamba2.mamba2_apply(p["mamba"], h, cfg, rules,
-                                           mode=mode, cache=cache, pos=pos)
-        x = x + y
+                                           mode=mode, cache=cache, pos=pos,
+                                           lengths=lengths)
+        x = _residual(x, y, cfg)
     elif kind == MLSTM:
         y, new_cache = xlstm.mlstm_apply(p["mlstm"], h, cfg, rules,
                                          mode=mode, cache=cache, pos=pos)
@@ -140,10 +154,22 @@ def _block_apply(kind, p, x, cfg, rules, mode, cache, pos, layer=None,
         y, new_cache = xlstm.slstm_apply(p["slstm"], h, cfg, rules,
                                          mode=mode, cache=cache, pos=pos)
         x = x + y
+    if kind == ATTN:
+        x = x + mlp(p["mlp"], rmsnorm(p["ln2"], x, eps), cfg)
+    elif kind in (ATTN_MOE, MAMBA2_MOE):
+        m = moe_mod.moe_apply(p["moe"], rmsnorm(p["ln2"], x, eps), cfg,
+                              rules, count_mask)
+        if count_mask is not None:
+            m, counts = m
+        x = _residual(x, m, cfg)
     if rules is not None:
         seq = "act_seq" if cfg.seq_shard else None
         x = rules.constrain(x, "batch", seq, None)
-    return x, new_cache
+    return x, new_cache, counts
+
+
+def _add_counts(acc, counts):
+    return acc if counts is None else acc + counts
 
 
 def _shared_attn_apply(p, x, cfg, rules, mode, cache, pos, layer=None,
@@ -163,77 +189,109 @@ def _paged(caches) -> bool:
                jax.tree_util.tree_flatten_with_path(caches)[0])
 
 
-def _decode_in_place(params, x, cfg, caches, pos, kv_backend):
+def _decode_in_place(params, x, cfg, caches, pos, kv_backend, count_mask):
     """The decode layer loop with every layer's caches as loop state: each
     block reads and updates its layer of the stacked caches in place (the
     paged KV pool through kernels/paged_attention), so no layer's cache is
-    sliced out as a scan input or stacked back as a scan output."""
+    sliced out as a scan input or stacked back as a scan output.  Returns
+    (x, caches, MoE counts summed over layers or None)."""
     unit = cfg.block_pattern
 
     def run(x, p_layers, c_layers, first):
         def body(carry, lp):
-            x, cs, i = carry
+            x, cs, i, acc = carry
             out = []
             for pi, kind in enumerate(unit):
-                x, c = _block_apply(kind, lp[pi], x, cfg, None, "decode",
-                                    cs[pi], pos, layer=i,
-                                    kv_backend=kv_backend)
+                x, c, counts = _block_apply(kind, lp[pi], x, cfg, None,
+                                            "decode", cs[pi], pos, layer=i,
+                                            kv_backend=kv_backend,
+                                            count_mask=count_mask)
+                acc = _add_counts(acc, counts)
                 out.append(c)
-            return (x, tuple(out), i + 1), None
+            return (x, tuple(out), i + 1, acc), None
 
-        (x, c_layers, _), _ = jax.lax.scan(
-            body, (x, c_layers, jnp.int32(first)), p_layers)
-        return x, c_layers
+        acc = None if count_mask is None else jnp.zeros(2, jnp.int32)
+        (x, c_layers, _, acc), _ = jax.lax.scan(
+            body, (x, c_layers, jnp.int32(first), acc), p_layers)
+        return x, c_layers, acc
 
     every = cfg.shared_attn_every
     if not every:
-        x, layers = run(x, params["layers"], caches["layers"], 0)
-        return x, {"layers": layers}
+        x, layers, acc = run(x, params["layers"], caches["layers"], 0)
+        return x, {"layers": layers}, acc
     # zamba2-style: the groups unrolled as in forward; the shared block's
     # caches are one stack indexed by the application
     p_all = params["layers"][0]
     layers, shared = caches["layers"], caches["shared"]
     for g, start in enumerate(range(0, cfg.n_layers, every)):
         sl = slice(start, min(start + every, cfg.n_layers))
-        x, layers = run(x, (jax.tree.map(lambda a: a[sl], p_all),), layers,
-                        start)
+        x, layers, _ = run(x, (jax.tree.map(lambda a: a[sl], p_all),),
+                           layers, start)
         if sl.stop - sl.start == every:
             x, shared = _shared_attn_apply(params["shared_attn"], x, cfg,
                                            None, "decode", shared, pos,
                                            layer=g, kv_backend=kv_backend)
-    return x, {"layers": layers, "shared": shared}
+    return x, {"layers": layers, "shared": shared}, None
+
+
+def _count_mask(x, mode, pos, lengths):
+    """(B, S) bool: the tokens whose routings are counted, those of active
+    sequences (decode: pos >= 0) or before each sequence's length."""
+    B, S = x.shape[:2]
+    if mode == "decode":
+        live = jnp.broadcast_to(jnp.asarray(pos), (B,)) >= 0
+        return jnp.broadcast_to(live[:, None], (B, S))
+    if lengths is None:
+        return jnp.ones((B, S), bool)
+    return jnp.arange(S)[None, :] < lengths[:, None]
 
 
 def forward(params, inputs, cfg: ModelConfig, rules=None, *, mode="train",
-            caches=None, pos=None, return_hidden=False, kv_backend=None):
-    """inputs: (B,S) int tokens or (B,S,d) embeds.  Returns (logits, caches).
+            caches=None, pos=None, return_hidden=False, kv_backend=None,
+            lengths=None, count_routes=False):
+    """inputs: (B,S) int tokens or (B,S,d) embeds.  Returns (logits, caches),
+    and with `count_routes` (MoE) also the int32 (2,) routings of counted
+    tokens to held experts and held experts hit, summed over layers
+    (moe.route_counts).  `lengths` (B,): a padded prefill's sequence
+    lengths (recurrent state, counted tokens).
 
     Decode with `kv_backend` given (kernels/paged_attention's backends), on
     a paged cache and unsharded (`rules` None), updates the caches in place
     (_decode_in_place); otherwise decode slices each layer's cache and
     gathers the pool in jnp."""
     x = embed(params["embed"], inputs, cfg)
+    if cfg.embedding_multiplier != 1.0:
+        x = x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
     if rules is not None:
         x = rules.constrain(x, "batch",
                             "act_seq" if cfg.seq_shard else None, None)
+    count_mask = _count_mask(x, mode, pos, lengths) if count_routes else None
+
+    def done(x, new_caches, counts):
+        out = _head(params, x, cfg, rules, new_caches, return_hidden)
+        return out + (counts,) if count_routes else out
+
     if (mode == "decode" and kv_backend is not None and rules is None
             and _paged(caches)):
-        x, new_caches = _decode_in_place(params, x, cfg, caches, pos,
-                                         kv_backend)
-        return _head(params, x, cfg, rules, new_caches, return_hidden)
+        return done(*_decode_in_place(params, x, cfg, caches, pos,
+                                      kv_backend, count_mask))
     unit = cfg.block_pattern
     use_cache = caches is not None
     every = cfg.shared_attn_every
 
-    def body(x, xs):
+    def body(carry, xs):
+        x, acc = carry
         layer_params, layer_caches = xs
         new_caches = []
         for pi, kind in enumerate(unit):
             c_i = layer_caches[pi] if use_cache else None
-            x, nc = _block_apply(kind, layer_params[pi], x, cfg, rules,
-                                 mode, c_i, pos)
+            x, nc, counts = _block_apply(kind, layer_params[pi], x, cfg,
+                                         rules, mode, c_i, pos,
+                                         lengths=lengths,
+                                         count_mask=count_mask)
+            acc = _add_counts(acc, counts)
             new_caches.append(nc if nc is not None else 0)
-        return x, tuple(new_caches)
+        return (x, acc), tuple(new_caches)
 
     if mode == "train" and cfg.remat != "none":
         policy = {"full": jax.checkpoint_policies.nothing_saveable,
@@ -241,10 +299,13 @@ def forward(params, inputs, cfg: ModelConfig, rules=None, *, mode="train",
                   }[cfg.remat]
         body = jax.checkpoint(body, policy=policy)
 
-    def seg_scan(x, p_seg, c_seg):
+    acc = None if count_mask is None else jnp.zeros(2, jnp.int32)
+
+    def seg_scan(x, acc, p_seg, c_seg):
         xs = (p_seg, c_seg if use_cache else jnp.zeros(
             (jax.tree.leaves(p_seg)[0].shape[0],)))
-        return jax.lax.scan(body, x, xs)
+        (x, acc), out = jax.lax.scan(body, (x, acc), xs)
+        return x, acc, out
 
     if every:
         # zamba2-style: shared attention block applied (with its own cache
@@ -262,7 +323,7 @@ def forward(params, inputs, cfg: ModelConfig, rules=None, *, mode="train",
             sl = slice(g * every, (g + 1) * every)
             p_seg = jax.tree.map(lambda a: a[sl], p_all)
             c_seg = jax.tree.map(lambda a: a[sl], c_all) if use_cache else None
-            x, c_out = seg_scan(x, (p_seg,), (c_seg,))
+            x, acc, c_out = seg_scan(x, acc, (p_seg,), (c_seg,))
             if use_cache:
                 seg_caches.append(c_out[0])
             sc = (jax.tree.map(lambda a: a[g], caches["shared"])
@@ -275,7 +336,7 @@ def forward(params, inputs, cfg: ModelConfig, rules=None, *, mode="train",
             sl = slice(groups * every, cfg.n_layers)
             p_seg = jax.tree.map(lambda a: a[sl], p_all)
             c_seg = jax.tree.map(lambda a: a[sl], c_all) if use_cache else None
-            x, c_out = seg_scan(x, (p_seg,), (c_seg,))
+            x, acc, c_out = seg_scan(x, acc, (p_seg,), (c_seg,))
             if use_cache:
                 seg_caches.append(c_out[0])
         new_caches = None
@@ -288,10 +349,10 @@ def forward(params, inputs, cfg: ModelConfig, rules=None, *, mode="train",
                                           axis=0), *shared_caches),
             }
     else:
-        x, layer_caches_out = seg_scan(
-            x, params["layers"], caches["layers"] if use_cache else None)
+        x, acc, layer_caches_out = seg_scan(
+            x, acc, params["layers"], caches["layers"] if use_cache else None)
         new_caches = {"layers": layer_caches_out} if use_cache else None
-    return _head(params, x, cfg, rules, new_caches, return_hidden)
+    return done(x, new_caches, acc)
 
 
 def _head(params, x, cfg, rules, new_caches, return_hidden):
@@ -299,6 +360,8 @@ def _head(params, x, cfg, rules, new_caches, return_hidden):
     if return_hidden:
         return x, new_caches
     logits = unembed(params["embed"], x, cfg)
+    if cfg.logits_scaling != 1.0:
+        logits = logits / jnp.asarray(cfg.logits_scaling, logits.dtype)
     if rules is not None:
         logits = rules.constrain(logits, "batch", None, "vocab")
     return logits, new_caches

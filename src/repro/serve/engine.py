@@ -15,6 +15,11 @@ sequence, ragged positions via the per-seq `pos` vector, -1 for a free
 slot).  The decode step reads and writes the pool in place through
 kernels/paged_attention: each active sequence's live blocks, none of a free
 slot's.
+
+A hybrid model's recurrent state (Mamba-2: SSM state and conv tail, per
+layer and slot) sits next to the pool: admission zeroes the slot's and its
+prefill, told the prompt's length, writes it; the decode step updates it
+in place (every slot's, free ones too); compaction leaves it alone.
 """
 from __future__ import annotations
 
@@ -27,7 +32,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.configs.base import ModelConfig
+from repro.configs.base import MAMBA2, MAMBA2_MOE, ModelConfig
 from repro.core import trace
 from repro.kernels.kv_compaction.ops import compact_kv_pool
 from repro.models import forward, init_cache, init_params
@@ -65,6 +70,15 @@ class ServingEngine:
         # the pool lives on a TPU, the jnp oracle elsewhere; tests may force
         # "pallas_interpret"
         self.kv_backend = kv_backend or _kv_backend_for(self.caches)
+        # an MoE model's prefill and decode also return their routings to
+        # held experts; its prefill, and a recurrent model's, is told the
+        # prompt's length (the state stops there, padding is not counted)
+        self._counts = cfg.is_moe
+        self._lengths = self._counts or bool(
+            {MAMBA2, MAMBA2_MOE} & set(cfg.pattern_for_layers()))
+        self._state_bytes_per_slot = sum(
+            a.nbytes // a.shape[1] for g in self.caches["layers"]
+            if "pool_k" not in g for a in jax.tree.leaves(g))
         self.pos = np.zeros(max_slots, np.int64)
         self.active: Dict[int, Request] = {}
         self.queue: "collections.deque[Request]" = collections.deque()
@@ -75,17 +89,17 @@ class ServingEngine:
         self._rid = 0
 
         def decode_fn(params, caches, tokens, pos):
-            logits, new_caches = forward(params, tokens, cfg, rules,
-                                         mode="decode", caches=caches,
-                                         pos=pos, kv_backend=self.kv_backend)
-            return jnp.argmax(logits[:, -1], axis=-1), new_caches
+            out = forward(params, tokens, cfg, rules, mode="decode",
+                          caches=caches, pos=pos, kv_backend=self.kv_backend,
+                          count_routes=self._counts)
+            return (jnp.argmax(out[0][:, -1], axis=-1),) + out[1:]
 
         self._decode = jax.jit(decode_fn, donate_argnums=(1,))
 
-        def prefill_fn(params, caches, tokens):
-            logits, new_caches = forward(params, tokens, cfg, rules,
-                                         mode="prefill", caches=caches)
-            return logits, new_caches
+        def prefill_fn(params, caches, tokens, lengths=None):
+            return forward(params, tokens, cfg, rules, mode="prefill",
+                           caches=caches, lengths=lengths,
+                           count_routes=self._counts)
 
         self._prefill = jax.jit(prefill_fn)
 
@@ -116,15 +130,21 @@ class ServingEngine:
             plen = len(req.prompt)
             assert plen + req.max_new <= self.max_seq
             with trace.span("serve.admit", prompt_tokens=plen,
-                            padded_tokens=self.max_seq):
+                            padded_tokens=self.max_seq) as sid:
                 # fragmented allocation: reused slots get scrambled blocks
                 sub = self._slot_cache(slot)
                 sub = self._fresh_slot_tables(sub)
                 toks = np.zeros((1, self.max_seq), np.int32)
                 toks[0, :plen] = req.prompt
-                logits, sub = self._prefill(self.params, sub,
-                                            jnp.asarray(toks))
+                kw = ({"lengths": jnp.asarray([plen], jnp.int32)}
+                      if self._lengths else {})
+                logits, sub, *counts = self._prefill(
+                    self.params, sub, jnp.asarray(toks), **kw)
                 nxt = int(jnp.argmax(logits[0, plen - 1]))
+                if counts and sid is not None:
+                    # indexed on the host: a device index would compile
+                    trace.tag(sid, moe_routes_local=int(
+                        np.asarray(counts[0])[0]))
                 req.out.append(nxt)
                 self._write_slot_cache(slot, sub)
             self.pos[slot] = plen
@@ -165,14 +185,22 @@ class ServingEngine:
             live = pos[pos >= 0].astype(np.int64)
             trace.tag(sid, kv_blocks=int(np.sum(-(-(live + 1) // bs))),
                       pool_blocks=self.max_slots * (self.max_seq // bs))
+            if self._state_bytes_per_slot:
+                # recurrent state of the active slots, read and written
+                trace.tag(sid, ssm_state_bytes=2 * len(live)
+                          * self._state_bytes_per_slot)
         if self.cfg.input_kind == "embeds":
             tok_in = jnp.zeros((self.max_slots, 1, self.cfg.d_model),
                                jnp.dtype(self.cfg.param_dtype))
         else:
             tok_in = jnp.asarray(tokens)
-        nxt, self.caches = self._decode(self.params, self.caches, tok_in,
-                                        jnp.asarray(pos))
+        nxt, self.caches, *counts = self._decode(
+            self.params, self.caches, tok_in, jnp.asarray(pos))
         nxt = np.asarray(nxt)
+        if counts and sid is not None:
+            routes, hit = np.asarray(counts[0])
+            trace.tag(sid, moe_routes_local=int(routes),
+                      moe_experts_hit=int(hit))
         produced = 0
         for slot in list(self.active):
             req = self.active[slot]

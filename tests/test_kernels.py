@@ -165,6 +165,33 @@ def test_compaction_is_permutation_inverse(B, nblk, bs, C, seed):
     assert np.array_equal(np.asarray(out2), np.asarray(out))
 
 
+@pytest.mark.parametrize("table", ["scrambled", "identity"])
+def test_paged_attention_with_a_configured_scale(table):
+    """granite-4.0-h's softmax scale, 1/128 at head size 128 (not
+    128 ** -0.5): the kernel matches its oracle at that scale, and the
+    oracle is plain softmax attention at it (f32: both within rounding)."""
+    B, nblk, scale = len(CELL_LENGTHS), 4, 1 / 128
+    heads = dict(CELL_HEADS, dt=jnp.float32)
+    q, pk, pv, tbl = _paged_inputs(B, nblk=nblk, table=table, **heads)
+    lengths = jnp.array(CELL_LENGTHS, jnp.int32)
+    ref = paged_decode_attention(q, pk, pv, tbl, lengths, 1,
+                                 backend="reference", scale=scale)
+    out = paged_decode_attention(q, pk, pv, tbl, lengths, 1,
+                                 backend="pallas_interpret", scale=scale)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               **tol(jnp.float32))
+    rep = heads["nh"] // heads["nkv"]
+    for b, n in enumerate(CELL_LENGTHS[1:], 1):
+        k = np.asarray(pk[1, b])[np.asarray(tbl[b])].reshape(-1, 8, 128)[:n]
+        v = np.asarray(pv[1, b])[np.asarray(tbl[b])].reshape(-1, 8, 128)[:n]
+        k, v = np.repeat(k, rep, 1), np.repeat(v, rep, 1)
+        s = np.einsum("hd,khd->hk", np.asarray(q[b]), k) * scale
+        p = np.exp(s - s.max(-1, keepdims=True))
+        want = np.einsum("hk,khd->hd", p / p.sum(-1, keepdims=True), v)
+        np.testing.assert_allclose(np.asarray(ref[b]), want, rtol=1e-4,
+                                   atol=1e-4)
+
+
 def test_paged_attention_invariant_under_compaction():
     """Attention(q, pool, table) == Attention(q, compact(pool), identity) —
     the kernel-level statement of the paper's GC correctness."""

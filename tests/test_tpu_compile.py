@@ -2,7 +2,8 @@
 runs): the kv_compaction kernel at SmolLM-135M serving widths (the chip's
 compiler accepts `compact_kv_pool(backend="pallas")`, the program holds the
 Pallas custom call, and it fits one chip's HBM), and the paged decode step
-at the serving cell's attention widths, in place.
+at the serving cells' attention widths, in place: Qwen3-8B's, and
+granite-4.0-h's, whose Mamba-2 state is updated in place too.
 
 The topology is described only inside fixtures: describing it loads the TPU
 compiler library, which one process at a time may hold."""
@@ -140,3 +141,41 @@ def test_the_gathering_decode_step_holds_more_than_a_layers_pool(one_chip):
     compiled = _decode_step(one_chip)
     assert "paged_decode_attention" not in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes > LAYER_POOL_BYTES
+
+
+# granite-4.0-h at its published widths, one Mamba-2 + MoE layer and the
+# attention + MoE layer, 64 slots of 3,584 tokens, 9 of 72 experts held
+GRANITE = get("granite_4_0_h_small").replace(
+    n_layers=2, block_pattern=("mamba2_moe", "attn_moe"), vocab_size=25088,
+    experts_held=9)
+GRANITE_SLOTS, GRANITE_SEQ = 64, 3584
+LAYER_STATE_BYTES = GRANITE_SLOTS * 128 * 64 * 128 * 4    # 268 MB of fp32
+
+
+def test_granite_decode_step_updates_its_state_in_place_for_v5e(one_chip):
+    """The engine's decode step of the hybrid, with its route counts: the
+    paged-attention kernel is in it, and its temporaries stay under one
+    layer's SSM state (the caches are donated and aliased)."""
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda: init_params(jax.random.PRNGKey(0), GRANITE)))
+    caches = on_chip(jax.eval_shape(
+        lambda: init_cache(GRANITE, GRANITE_SLOTS, GRANITE_SEQ, "paged")))
+
+    def step(params, caches, tokens, pos):     # ServingEngine's decode_fn
+        out = forward(params, tokens, GRANITE, None, mode="decode",
+                      caches=caches, pos=pos, kv_backend="pallas",
+                      count_routes=True)
+        return (jnp.argmax(out[0][:, -1], axis=-1),) + out[1:]
+
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+        params, caches, i32(GRANITE_SLOTS, 1), i32(GRANITE_SLOTS)).compile()
+    assert "paged_decode_attention" in compiled.as_text()
+    m = compiled.memory_analysis()
+    assert m.temp_size_in_bytes < LAYER_STATE_BYTES
+    assert m.alias_size_in_bytes >= sum(              # tiles may pad
+        a.size * a.dtype.itemsize for a in jax.tree.leaves(caches))
