@@ -12,7 +12,9 @@ old pool stays valid, so in-flight lookups never see a hole.
 Scheduler: admit-on-free-slot continuous batching; one engine `step()` =
 (admit+prefill new requests) + (one lockstep decode token for every active
 sequence, ragged positions via the per-seq `pos` vector, -1 for a free
-slot).  The decode step reads and writes the pool in place through
+slot).  Admission prefills a prompt padded to the smallest of a few bucket
+lengths that holds it (`prefill_buckets`), one compiled prefill a bucket.
+The decode step reads and writes the pool in place through
 kernels/paged_attention: each active sequence's live blocks, none of a free
 slot's.
 
@@ -76,6 +78,7 @@ class ServingEngine:
         self._counts = cfg.is_moe
         self._lengths = self._counts or bool(
             {MAMBA2, MAMBA2_MOE} & set(cfg.pattern_for_layers()))
+        self._buckets = prefill_buckets(max_seq, cfg.kv_block_size)
         self._state_bytes_per_slot = sum(
             a.nbytes // a.shape[1] for g in self.caches["layers"]
             if "pool_k" not in g for a in jax.tree.leaves(g))
@@ -129,12 +132,13 @@ class ServingEngine:
             req.slot = slot
             plen = len(req.prompt)
             assert plen + req.max_new <= self.max_seq
+            padded = next(b for b in self._buckets if b >= plen)
             with trace.span("serve.admit", prompt_tokens=plen,
-                            padded_tokens=self.max_seq) as sid:
+                            padded_tokens=padded) as sid:
                 # fragmented allocation: reused slots get scrambled blocks
                 sub = self._slot_cache(slot)
                 sub = self._fresh_slot_tables(sub)
-                toks = np.zeros((1, self.max_seq), np.int32)
+                toks = np.zeros((1, padded), np.int32)
                 toks[0, :plen] = req.prompt
                 kw = ({"lengths": jnp.asarray([plen], jnp.int32)}
                       if self._lengths else {})
@@ -246,6 +250,25 @@ class ServingEngine:
         with trace.span("serve.compact"):
             self.caches = compact_caches(self.caches, backend)
         self.compactions += 1
+
+
+def prefill_buckets(max_seq: int, block: int) -> List[int]:
+    """The lengths admission pads a prompt to, ascending: the powers of two
+    from 128 below `max_seq` that are whole KV blocks, then `max_seq`.
+
+    A prefill of S tokens writes S // block blocks through the table, runs
+    the Mamba-2 SSD in chunks of min(128, S) and causal attention in query
+    chunks of min(512, S), so S must be a multiple of each: a power of two
+    from 128 is a multiple of 128 and, above 512, of 512; one that is not
+    whole blocks is skipped; `max_seq` is all three already.  Padding past
+    the prompt changes nothing before it: attention is causal and a
+    recurrent layer is told the prompt's length."""
+    out, b = [], 128
+    while b < max_seq:
+        if b % block == 0:
+            out.append(b)
+        b *= 2
+    return out + [max_seq]
 
 
 def _kv_backend_for(caches) -> str:

@@ -19,6 +19,7 @@ from repro.core import trace
 from repro.models import forward, init_cache, init_params
 from repro.models import moe as moe_mod
 from repro.serve.engine import ServingEngine
+from test_serving import admit_both_ways, prompt_kv
 
 _REF = Path(__file__).resolve().parents[1] / "benchmarks" / "chip" / \
     "reference" / "granite4h.py"
@@ -292,3 +293,27 @@ def test_an_attention_moe_admission_counts_only_the_prompt(monkeypatch):
     admits = [s for s in t.spans if s.name == "serve.admit"]
     assert [s.tags["moe_routes_local"] for s in admits] == \
         [6 * cfg.n_layers * cfg.top_k]
+
+
+def test_a_bucketed_admission_equals_one_padded_to_max_seq(monkeypatch):
+    """Prompts in two buckets below max_seq (1,024: eight SSD chunks)
+    leave their slot's first token, K/V up to the prompt's length, every
+    Mamba-2 layer's SSM state and conv tail, and serve.admit's route
+    count, as a prefill padded to max_seq does."""
+    t = trace.Tracer()
+    monkeypatch.setattr(trace, "_ACTIVE", t)
+    eng = ServingEngine(CFG, PARAMS, max_slots=2, max_seq=1024, seed=9)
+    rng = np.random.default_rng(9)
+    for n in (100, 300):                            # buckets 128 and 512
+        req, got, first, want, routes = admit_both_ways(
+            eng, rng.integers(0, CFG.vocab_size, n).tolist())
+        assert req.out == [first]
+        admit = [s for s in t.spans if s.name == "serve.admit"][-1]
+        assert admit.tags["moe_routes_local"] == routes
+        for g, w in zip(got["layers"], want["layers"]):
+            pairs = (zip(prompt_kv(g, n, CFG.kv_block_size),
+                         prompt_kv(w, n, CFG.kv_block_size))
+                     if "pool_k" in g else
+                     ((g["ssm"], w["ssm"]), (g["conv"], w["conv"])))
+            for a, b in pairs:
+                np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5)

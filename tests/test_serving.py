@@ -5,8 +5,9 @@ import numpy as np
 import pytest
 
 from repro.configs import get
+from repro.core import trace
 from repro.models import forward, init_params
-from repro.serve.engine import ServingEngine
+from repro.serve.engine import ServingEngine, prefill_buckets
 
 CFG = get("smollm_135m", smoke=True).replace(param_dtype="float32",
                                              kv_block_size=8)
@@ -126,3 +127,103 @@ def test_a_decode_step_writes_only_the_active_rows(backend):
             changed = np.argwhere(np.any(old[name] != np.asarray(new[name]),
                                          axis=(-2, -1)))
             assert {tuple(int(i) for i in c) for c in changed} == want
+
+
+# the ladders of the benchmark's configurations (max_seq 2,048 and 3,584,
+# blocks of 64), of smoke engines and of blocks longer than 128 tokens; each
+# with the bucket a prompt of each length is padded to
+@pytest.mark.parametrize("max_seq, block, ladder, padded", [
+    (2048, 64, [128, 256, 512, 1024, 2048],
+     {1: 128, 127: 128, 128: 128, 129: 256, 512: 512, 513: 1024,
+      2048: 2048}),
+    (3584, 64, [128, 256, 512, 1024, 2048, 3584],
+     {1: 128, 127: 128, 128: 128, 129: 256, 512: 512, 513: 1024,
+      2049: 3584, 3584: 3584}),
+    (256, 8, [128, 256], {1: 128, 127: 128, 128: 128, 129: 256, 256: 256}),
+    (32, 8, [32], {1: 32, 32: 32}),
+    (1024, 256, [256, 512, 1024], {1: 256, 257: 512, 1024: 1024}),
+])
+def test_prefill_buckets(max_seq, block, ladder, padded):
+    """Each bucket is whole KV blocks and whole SSD chunks (128, or the
+    whole prefill where shorter), and above 512 whole attention query
+    chunks; max_seq is the top one."""
+    assert prefill_buckets(max_seq, block) == ladder
+    for b in ladder:
+        assert b % block == 0 and b % min(128, b) == 0
+        assert b <= 512 or b % 512 == 0
+    for n, want in padded.items():
+        assert min(b for b in ladder if b >= n) == want
+
+
+def test_admission_pads_to_the_bucket_and_compiles_once_a_bucket(
+        monkeypatch):
+    """serve.admit's padded_tokens is the prompt's bucket, and a second
+    prompt in a bucket already used compiles no prefill."""
+    t = trace.Tracer()
+    monkeypatch.setattr(trace, "_ACTIVE", t)
+    eng = ServingEngine(CFG, PARAMS, max_slots=2, max_seq=512, seed=7)
+    rng = np.random.default_rng(7)
+    compiled = []
+    for n in (1, 127, 128, 129, 256, 257, 500):
+        eng.submit(rng.integers(0, CFG.vocab_size, n).tolist(), max_new=2)
+        eng.run_until_drained()
+        compiled.append(eng._prefill._cache_size())
+    admits = [s for s in t.spans if s.name == "serve.admit"]
+    assert [(s.tags["prompt_tokens"], s.tags["padded_tokens"])
+            for s in admits] == [(1, 128), (127, 128), (128, 128),
+                                 (129, 256), (256, 256), (257, 512),
+                                 (500, 512)]
+    assert compiled == [1, 1, 1, 2, 2, 3, 3]
+
+
+def admit_both_ways(eng, prompt):
+    """Admit `prompt` through the engine, at its bucket, and prefill the
+    same slot from the same fresh state and block table padded to max_seq,
+    by a plain call of the engine's prefill.  Returns the request, the
+    slot's caches after admission, and the max_seq prefill's first token,
+    slot caches and route counts (an MoE model's, else None)."""
+    req = eng.submit(prompt, max_new=2)
+    eng._admit()
+    slot = eng._slot_cache(req.slot)
+    fresh = jax.tree.map(
+        lambda a: a if a.dtype == jnp.int32 else jnp.zeros_like(a), slot)
+    n = len(prompt)
+    toks = np.zeros((1, eng.max_seq), np.int32)
+    toks[0, :n] = prompt
+    kw = {"lengths": jnp.asarray([n], jnp.int32)} if eng._lengths else {}
+    logits, want, *counts = eng._prefill(eng.params, fresh,
+                                         jnp.asarray(toks), **kw)
+    return (req, jax.tree.map(np.asarray, slot),
+            int(np.argmax(np.asarray(logits)[0, n - 1])),
+            jax.tree.map(np.asarray, want),
+            int(np.asarray(counts[0])[0]) if counts else None)
+
+
+def prompt_kv(group, n, bs):
+    """Each layer's K and V of a one-slot attention cache group's first
+    n positions, read through its block table."""
+    blocks = group["table"][:, 0, :-(-n // bs)]             # (layers, nb)
+    layers = np.arange(blocks.shape[0])[:, None]
+    return [group[name][layers, 0, blocks].reshape(
+        blocks.shape[0], -1, *group[name].shape[-2:])[:, :n]
+        for name in ("pool_k", "pool_v")]
+
+
+QWEN3 = get("qwen3_8b", smoke=True).replace(param_dtype="float32")
+
+
+def test_a_bucketed_admission_equals_one_padded_to_max_seq():
+    """A Qwen3-shaped model: prompts in two buckets below max_seq (1,024:
+    two attention query chunks) leave their slot's first token and K/V up
+    to the prompt's length as a prefill padded to max_seq does."""
+    eng = ServingEngine(QWEN3, init_params(jax.random.PRNGKey(1), QWEN3),
+                        max_slots=2, max_seq=1024, seed=8)
+    rng = np.random.default_rng(8)
+    for n in (100, 300):                            # buckets 128 and 512
+        req, got, first, want, _ = admit_both_ways(
+            eng, rng.integers(0, QWEN3.vocab_size, n).tolist())
+        assert req.out == [first]
+        for g, w in zip(got["layers"], want["layers"]):
+            for a, b in zip(prompt_kv(g, n, QWEN3.kv_block_size),
+                            prompt_kv(w, n, QWEN3.kv_block_size)):
+                np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5)
