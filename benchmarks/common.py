@@ -20,6 +20,7 @@ import numpy as np
 from repro.core.cluster import Cluster
 
 FULL = os.environ.get("REPRO_BENCH_FULL") == "1"
+ARTIFACT_DIR = os.path.join(os.path.dirname(__file__), "..")
 ENGINES = ["original", "pasv", "tikv", "dwisckey", "lsmraft", "nezha_nogc",
            "nezha"]
 
@@ -100,10 +101,11 @@ def parse_derived(derived: str) -> Dict[str, object]:
 
 def write_artifact(fig: str, rows: List[Tuple[str, float, str]],
                    extra: Dict[str, object] = None) -> str:
-    """Persist one figure's results as BENCH_<fig>.json at the repo root so
-    the perf trajectory is tracked (and diffed) across PRs."""
+    """Persist one figure's results as BENCH_<fig>.json in ARTIFACT_DIR
+    (the repo root) so the perf trajectory is tracked (and diffed) across
+    PRs."""
     import json
-    path = os.path.join(os.path.dirname(__file__), "..", f"BENCH_{fig}.json")
+    path = os.path.join(ARTIFACT_DIR, f"BENCH_{fig}.json")
     doc = {"fig": fig, "full": FULL,
            "rows": [{"name": n, "us_per_call": round(us, 2),
                      "derived": parse_derived(d)} for n, us, d in rows]}

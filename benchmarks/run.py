@@ -1,9 +1,9 @@
-"""Benchmark harness — one module per paper table/figure + the roofline
-table from the dry-run.  Prints ``name,us_per_call,derived`` CSV.
+"""Benchmark harness — one module per paper table/figure.  Prints
+``name,us_per_call,derived`` CSV.
 
   PYTHONPATH=src python -m benchmarks.run            # quick mode
   REPRO_BENCH_FULL=1 ... python -m benchmarks.run    # ~10x sizes
-  python -m benchmarks.run --only fig4,roofline   # roofline: on request only
+  python -m benchmarks.run --only fig4,fig12         # a subset
   python -m benchmarks.run --smoke                   # tiny CI gate (tier-1)
 """
 from __future__ import annotations
@@ -60,8 +60,10 @@ def smoke() -> int:
          structurally on the span/event stream); every synced nezha put
          carries EXACTLY one value-log fsync on the leader critical
          path; and the disabled tracer is free — the untraced same-seed
-         run has the identical SimNet trace and Metrics, within noise
-         on wall clock,
+         run records no span, event or counter into the trace module
+         (the traced run does) and has the identical SimNet trace and
+         Metrics; its wall-clock ratio to the traced run is printed,
+         not gated (host load moves it),
      10. sharding gate (fig_shard at smoke scale): N=4 range shards —
          each its own Raft group over one SimNet — scale put throughput
          >= 2x over the 1-shard fabric and monotonically 1 -> 2 -> 4
@@ -323,9 +325,12 @@ def smoke() -> int:
     if tr["disabled_footprint"].get("sim_identical") != 1:
         show("smoke/FAIL", 0, "tracer_install_perturbed_the_simulation")
         ok = False
-    if tr["disabled_footprint"].get("wall_ratio", 99) > 2.5:
-        show("smoke/FAIL", 0, "tracing_overhead_unbounded_wall_ratio="
-             f"{tr['disabled_footprint'].get('wall_ratio', 99):.2f}")
+    fp = tr["disabled_footprint"]
+    if fp.get("disabled_records", 1) != 0 or \
+            fp.get("traced_records", 0) <= 0:
+        show("smoke/FAIL", 0, "disabled_tracer_recorded_"
+             f"{fp.get('disabled_records', 1):.0f}_traced_recorded_"
+             f"{fp.get('traced_records', 0):.0f}")
         ok = False
     if sh["shards=4"].get("scaling_x", 0) < 2.0:
         show("smoke/FAIL", 0, "sharding_4x_fabric_scaled_puts_only_"
@@ -371,6 +376,7 @@ def smoke() -> int:
              f";trace_violations="
              f"{tr['chaos_audit'].get('causality_violations'):.0f}"
              f";trace_vlog_fsyncs_per_put=1"
+             f";trace_disabled_records=0"
              f";trace_wall_ratio="
              f"{tr['disabled_footprint'].get('wall_ratio'):.2f}"
              f";shard_scaling_x={sh['shards=4'].get('scaling_x', 0):.2f}"
@@ -384,9 +390,7 @@ def smoke() -> int:
 def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default="",
-                    help="comma-separated subset: fig4..fig12, fig_*, or "
-                         "roofline (needs results/dryrun.json; never run "
-                         "by default)")
+                    help="comma-separated subset: fig4..fig12, fig_*")
     ap.add_argument("--smoke", action="store_true",
                     help="tiny all-engine assertion run (CI gate)")
     args = ap.parse_args()
@@ -399,7 +403,7 @@ def main() -> int:
                             fig7_scan_length, fig8_ycsb, fig9_scalability,
                             fig10_gc_impact, fig11_recovery, fig12_batching,
                             fig_reads, fig_runship, fig_shard, fig_tail,
-                            fig_trace, roofline)
+                            fig_trace)
 
     suites = {
         "fig4": lambda: fig4_put.run()[0],
@@ -417,9 +421,8 @@ def main() -> int:
         "fig_tail": fig_tail.run,
         "fig_trace": fig_trace.run,
     }
-    on_request = {"roofline": roofline.run}
     only = [n for n in args.only.split(",") if n]
-    unknown = sorted(set(only) - set(suites) - set(on_request))
+    unknown = sorted(set(only) - set(suites))
     if unknown:
         ap.error(f"unknown suite(s): {','.join(unknown)}")
     chosen = only or list(suites)
@@ -427,7 +430,7 @@ def main() -> int:
     t0 = time.time()
     failed = []
     for name in chosen:
-        fn = suites.get(name) or on_request[name]
+        fn = suites[name]
         t1 = time.time()
         try:
             rows = fn()

@@ -1,2 +1,2 @@
-from repro.configs.base import (ARCH_IDS, SHAPES, ModelConfig, ShapeConfig,
-                                cell_is_skipped, get)  # noqa: F401
+from repro.configs.base import (ARCH_IDS, ModelConfig, ShapeConfig,  # noqa: F401
+                                get)

@@ -119,15 +119,6 @@ class ModelConfig:
     def attention_free(self) -> bool:
         return not self.has_attention
 
-    @property
-    def subquadratic(self) -> bool:
-        """True if sequence mixing is sub-quadratic (SSM / linear recurrent),
-        allowing the long_500k shape."""
-        kinds = set(self.pattern_for_layers())
-        return kinds <= {MAMBA2, MLSTM, SLSTM} or (
-            kinds <= {MAMBA2, MLSTM, SLSTM, ATTN} and self.shared_attn_every > 0
-            and ATTN not in kinds)
-
     def replace(self, **kw) -> "ModelConfig":
         return dataclasses.replace(self, **kw)
 
@@ -190,13 +181,6 @@ class ShapeConfig:
     kind: str  # train | prefill | decode
 
 
-SHAPES = {
-    "train_4k": ShapeConfig("train_4k", 4_096, 256, "train"),
-    "prefill_32k": ShapeConfig("prefill_32k", 32_768, 32, "prefill"),
-    "decode_32k": ShapeConfig("decode_32k", 32_768, 128, "decode"),
-    "long_500k": ShapeConfig("long_500k", 524_288, 1, "decode"),
-}
-
 ARCH_IDS = [
     "smollm_135m", "deepseek_7b", "qwen2_72b", "qwen3_8b", "musicgen_medium",
     "chameleon_34b", "zamba2_1p2b", "olmoe_1b_7b", "dbrx_132b", "xlstm_125m",
@@ -207,11 +191,3 @@ ARCH_IDS = [
 def get(name: str, smoke: bool = False) -> ModelConfig:
     mod = importlib.import_module(f"repro.configs.{name.replace('-', '_')}")
     return mod.SMOKE if smoke else mod.CONFIG
-
-
-def cell_is_skipped(cfg: ModelConfig, shape: ShapeConfig) -> Optional[str]:
-    """Return skip reason or None.  long_500k only runs for sub-quadratic archs
-    (see DESIGN.md §5)."""
-    if shape.name == "long_500k" and not cfg.subquadratic:
-        return "long_500k skipped: pure full-attention arch (quadratic prefill); see DESIGN.md"
-    return None

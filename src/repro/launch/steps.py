@@ -1,22 +1,20 @@
-"""Step factories: jitted, sharded train / prefill / decode steps.
+"""Step factories: the jitted, sharded train step and its state.
 
 Each factory closes over (cfg, mesh) and returns the jitted step plus the
-ShapeDtypeStruct input specs used both by the dry-run (lower/compile with no
-allocation) and by real execution (smoke tests, examples).
+shardings of its inputs; ``input_specs`` gives ShapeDtypeStruct stand-ins
+for a train batch (smoke tests, examples, the checkpoint runner).
 """
 from __future__ import annotations
 
-import functools
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig, ShapeConfig
-from repro.models import forward, init_cache, init_params, lm_loss
-from repro.sharding.rules import (Rules, cache_spec, make_rules, param_spec,
-                                  tree_specs)
+from repro.models import forward, init_params, lm_loss
+from repro.sharding.rules import Rules, make_rules, param_spec, tree_specs
 from repro.train.optimizer import OptHyper, adamw_update, init_opt_state
 
 PyTree = Any
@@ -24,37 +22,19 @@ PyTree = Any
 
 # ------------------------------------------------------------- input specs
 def input_specs(cfg: ModelConfig, shape: ShapeConfig) -> Dict[str, Any]:
-    """ShapeDtypeStruct stand-ins for every model input of this cell."""
+    """ShapeDtypeStruct stand-ins for a train batch of this shape."""
     B, S = shape.global_batch, shape.seq_len
-    tok_dt = jnp.int32
-    if shape.kind == "train":
-        if cfg.input_kind == "embeds":
-            tokens = jax.ShapeDtypeStruct((B, S, cfg.d_model), jnp.bfloat16)
-        else:
-            tokens = jax.ShapeDtypeStruct((B, S), tok_dt)
-        return {"tokens": tokens, "labels": jax.ShapeDtypeStruct((B, S), tok_dt)}
-    if shape.kind == "prefill":
-        if cfg.input_kind == "embeds":
-            return {"tokens": jax.ShapeDtypeStruct((B, S, cfg.d_model),
-                                                   jnp.bfloat16)}
-        return {"tokens": jax.ShapeDtypeStruct((B, S), tok_dt)}
-    # decode: one new token against a seq_len-deep cache
     if cfg.input_kind == "embeds":
-        tokens = jax.ShapeDtypeStruct((B, 1, cfg.d_model), jnp.bfloat16)
+        tokens = jax.ShapeDtypeStruct((B, S, cfg.d_model), jnp.bfloat16)
     else:
-        tokens = jax.ShapeDtypeStruct((B, 1), tok_dt)
-    return {"tokens": tokens, "pos": jax.ShapeDtypeStruct((), jnp.int32)}
+        tokens = jax.ShapeDtypeStruct((B, S), jnp.int32)
+    return {"tokens": tokens, "labels": jax.ShapeDtypeStruct((B, S),
+                                                             jnp.int32)}
 
 
 def batch_shardings(cfg, shape, rules: Rules):
-    specs = {}
-    ins = input_specs(cfg, shape)
-    for k, v in ins.items():
-        if k == "pos":
-            specs[k] = NamedSharding(rules.mesh, P())
-        else:
-            specs[k] = rules.sharding(v.shape, "batch")
-    return specs
+    return {k: rules.sharding(v.shape, "batch")
+            for k, v in input_specs(cfg, shape).items()}
 
 
 def abstract_state(cfg, key=None):
@@ -76,16 +56,6 @@ def state_shardings(cfg, rules: Rules):
         "v": tree_specs(st["v"], param_spec, rules),
         "step": NamedSharding(rules.mesh, P()),
     }
-
-
-def abstract_cache(cfg, shape: ShapeConfig, layout: str):
-    return jax.eval_shape(
-        lambda: init_cache(cfg, shape.global_batch, shape.seq_len, layout))
-
-
-def cache_shardings(cfg, shape, rules: Rules, layout: str):
-    ac = abstract_cache(cfg, shape, layout)
-    return tree_specs(ac, cache_spec, rules)
 
 
 # -------------------------------------------------------------- train step
@@ -168,47 +138,3 @@ def make_init_fn(cfg, mesh):
                 "step": jnp.zeros((), jnp.int32)}
 
     return jax.jit(init_fn, out_shardings=st_sh), st_sh
-
-
-# ------------------------------------------------------------ prefill step
-def make_prefill_step(cfg, mesh, shape: ShapeConfig, layout: str = "paged"):
-    seqshard = shape.global_batch == 1
-    rules = make_rules(mesh, seq_shard_cache=seqshard)
-
-    def prefill_step(params, batch):
-        tokens = batch["tokens"]
-        B = tokens.shape[0]
-        cache0 = init_cache(cfg, B, shape.seq_len, layout)
-        logits, cache = forward(params, tokens, cfg, rules, mode="prefill",
-                                caches=cache0)
-        next_tok = jnp.argmax(logits[:, -1:], axis=-1)
-        return next_tok, cache
-
-    p_sh = tree_specs(abstract_state(cfg)["params"], param_spec, rules)
-    b_sh = batch_shardings(cfg, shape, rules)
-    c_sh = cache_shardings(cfg, shape, rules, layout)
-    tok_out = rules.sharding((shape.global_batch, 1), "batch")
-    step = jax.jit(prefill_step, in_shardings=(p_sh, b_sh),
-                   out_shardings=(tok_out, c_sh))
-    return step, rules, p_sh, b_sh, c_sh
-
-
-# ------------------------------------------------------------- decode step
-def make_decode_step(cfg, mesh, shape: ShapeConfig, layout: str = "paged"):
-    seqshard = shape.global_batch == 1
-    rules = make_rules(mesh, seq_shard_cache=seqshard)
-
-    def decode_step(params, cache, batch):
-        tokens, pos = batch["tokens"], batch["pos"]
-        logits, new_cache = forward(params, tokens, cfg, rules, mode="decode",
-                                    caches=cache, pos=pos)
-        next_tok = jnp.argmax(logits[:, -1:], axis=-1)
-        return next_tok, new_cache
-
-    p_sh = tree_specs(abstract_state(cfg)["params"], param_spec, rules)
-    b_sh = batch_shardings(cfg, shape, rules)
-    c_sh = cache_shardings(cfg, shape, rules, layout)
-    tok_out = rules.sharding((shape.global_batch, 1), "batch")
-    step = jax.jit(decode_step, in_shardings=(p_sh, c_sh, b_sh),
-                   out_shardings=(tok_out, c_sh), donate_argnums=(1,))
-    return step, rules, p_sh, b_sh, c_sh

@@ -3,12 +3,15 @@
 Two decode cache layouts, mirroring the paper's storage states (DESIGN.md §2):
   * ``paged``  — block pool + per-sequence block table (scattered ValueLog):
                  (B, n_blocks, block, n_kv, hd) with a logical->physical table.
+                 Decode reads and writes it in place through
+                 kernels/paged_attention; this is the serving engine's layout.
   * ``dense``  — contiguous cache (sorted ValueLog, i.e. post-GC/compaction):
-                 (B, S, n_kv, hd).
+                 (B, S, n_kv, hd).  Decode writes the token's row and attends
+                 in jnp; it is the reference the paged decode is tested
+                 against.
 
-The train/prefill path is a pure-jnp flash-attention equivalent (query-chunked,
-f32 logsumexp) whose arithmetic matches kernels/flash_attention; on TPU the
-Pallas kernel is substituted via kernels.flash_attention.ops.
+Train and prefill run the pure-jnp query-chunked causal attention (f32
+logsumexp) on every platform; no Pallas kernel is substituted on a TPU.
 """
 from __future__ import annotations
 
@@ -148,14 +151,11 @@ def init_attn_cache(cfg, batch: int, max_seq: int, layout: str, dtype=None):
     }
 
 
-def _decode_attend(q, k_all, v_all, pos, nh, rules, scale):
+def _decode_attend(q, k_all, v_all, pos, nh, scale):
     """q:(B,1,nh,hd) vs full cache (B,S,nkv,hd) masked to <=pos.
 
-    Grouped (no KV repeat): the cache is read once — decode is HBM-bound and
-    an nh/nkv-fold repeat would overstate the memory roofline term 8x.  The
-    (nkv, rep) head split only touches q, which is tiny at decode.  The big
-    dims (batch, cache_seq) keep their sharding; softmax reductions over a
-    sharded S lower to the flash-decoding split-K all-reduce pattern."""
+    Grouped (no KV repeat): the cache is read once.  The (nkv, rep) head
+    split only touches q, which is tiny at decode."""
     B, S, nkv, hd = k_all.shape
     rep = nh // nkv
     qg = q.reshape(B, 1, nkv, rep, hd)
@@ -172,42 +172,22 @@ def _decode_attend(q, k_all, v_all, pos, nh, rules, scale):
     return o.reshape(B, 1, nh * hd)
 
 
-def attn_decode(params, cache, x, pos, cfg, rules):
-    """One-token decode. x:(B,1,d); pos: scalar OR per-sequence (B,) index
-    (continuous batching serves ragged sequences in one lockstep batch); a
-    negative pos marks a free sequence, whose token is written nowhere."""
-    B = x.shape[0]
-    nh, nkv, hd, bs = cfg.n_heads, cfg.n_kv_heads, cfg.hd, cfg.kv_block_size
+def attn_decode(params, cache, x, pos, cfg):
+    """One-token decode on the dense cache (k/v (B, S, nkv, hd)). x:(B,1,d);
+    pos: scalar OR per-sequence (B,) index (continuous batching serves
+    ragged sequences in one lockstep batch); a negative pos marks a free
+    sequence, whose token is written nowhere."""
+    B, S = cache["k"].shape[:2]
     pos_b = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,))
-    positions = jnp.maximum(pos_b, 0)[:, None]
-    q, k_new, v_new = _project_qkv(params, x, cfg, positions)
+    q, k_new, v_new = _project_qkv(params, x, cfg,
+                                   jnp.maximum(pos_b, 0)[:, None])
     bi = jnp.arange(B)
-    if "k" in cache:  # dense / compacted layout
-        k_all = cache["k"].at[bi, pos_b].set(k_new[:, 0])
-        v_all = cache["v"].at[bi, pos_b].set(v_new[:, 0])
-        new_cache = {"k": k_all, "v": v_all}
-        if rules is not None:
-            k_all = rules.constrain(k_all, "batch", "cache_seq")
-            v_all = rules.constrain(v_all, "batch", "cache_seq")
-    else:  # paged layout: write via block table, read via gather
-        blk, slot = _token_block(cache["table"], pos_b, bs)
-        pool_k = cache["pool_k"].at[bi, blk, slot].set(k_new[:, 0],
-                                                       mode="drop")
-        pool_v = cache["pool_v"].at[bi, blk, slot].set(v_new[:, 0],
-                                                       mode="drop")
-        new_cache = dict(cache, pool_k=pool_k, pool_v=pool_v)
-        tbl = cache["table"][..., None, None, None]              # (B,nblk,1,1,1)
-        k_all = jnp.take_along_axis(pool_k, tbl, axis=1)
-        v_all = jnp.take_along_axis(pool_v, tbl, axis=1)
-        n_blk = k_all.shape[1]
-        k_all = k_all.reshape(B, n_blk * bs, nkv, hd)
-        v_all = v_all.reshape(B, n_blk * bs, nkv, hd)
-        if rules is not None:
-            k_all = rules.constrain(k_all, "batch", "cache_seq")
-            v_all = rules.constrain(v_all, "batch", "cache_seq")
-    o = _decode_attend(q, k_all, v_all, pos, nh, rules, softmax_scale(cfg))
+    row = jnp.where(pos_b >= 0, pos_b, S)          # S: out of range, dropped
+    k_all = cache["k"].at[bi, row].set(k_new[:, 0], mode="drop")
+    v_all = cache["v"].at[bi, row].set(v_new[:, 0], mode="drop")
+    o = _decode_attend(q, k_all, v_all, pos, cfg.n_heads, softmax_scale(cfg))
     out = o.astype(x.dtype) @ params["wo_attn"]
-    return out, new_cache
+    return out, {"k": k_all, "v": v_all}
 
 
 def _token_block(table, pos_b, bs):
@@ -226,9 +206,9 @@ def attn_decode_in_place(params, cache, x, pos, cfg, layer, kv_backend):
     (layers, B, nblk, bs, nkv, hd), table (layers, B, nblk)), at `layer`:
     the token's K/V are written into their block of the pool in place and
     attention reads each sequence's live blocks through its table
-    (kernels/paged_attention, backend `kv_backend`).  Nothing of the pool is
-    sliced out or copied.  pos as in attn_decode: a free sequence (pos < 0)
-    writes nothing and reads nothing."""
+    (kernels/paged_attention, backend `kv_backend`; None: its default).
+    Nothing of the pool is sliced out or copied.  pos as in attn_decode: a
+    free sequence (pos < 0) writes nothing and reads nothing."""
     B = x.shape[0]
     bs = cfg.kv_block_size
     pos_b = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,))
@@ -249,18 +229,20 @@ def attn_decode_in_place(params, cache, x, pos, cfg, layer, kv_backend):
 
 def attn_apply(params, x, cfg, rules, *, mode="train", cache=None, pos=None,
                chunk=512, layer=None, kv_backend=None):
-    """Unified entry. Returns (out, new_cache).  Decode with `layer` given
-    works on every layer's stacked paged cache in place
-    (attn_decode_in_place)."""
+    """Unified entry. Returns (out, new_cache).  Decode follows the cache's
+    layout: a paged cache is every layer's stacked cache, read and written
+    in place at `layer` (attn_decode_in_place, backend `kv_backend`); a
+    dense one is this layer's (attn_decode).  Train and prefill run the
+    chunked causal attention, under `rules` where given."""
     if mode == "decode":
-        if layer is not None:
+        if "pool_k" in cache:
             return attn_decode_in_place(params, cache, x, pos, cfg, layer,
                                         kv_backend)
-        return attn_decode(params, cache, x, pos, cfg, rules)
+        return attn_decode(params, cache, x, pos, cfg)
     B, S, _ = x.shape
     positions = jnp.arange(S)[None]
     q, k, v = _project_qkv(params, x, cfg, positions)
-    if rules is not None and cfg.attn_seq_parallel and mode != "decode":
+    if rules is not None and cfg.attn_seq_parallel:
         # context parallelism: q rows sharded over `model`, K/V replicated;
         # each rank computes its strip of the score matrix (no head-count
         # divisibility requirement — see DESIGN.md §6 / EXPERIMENTS §Perf)

@@ -184,11 +184,6 @@ def _shared_attn_apply(p, x, cfg, rules, mode, cache, pos, layer=None,
     return x, new_cache
 
 
-def _paged(caches) -> bool:
-    return any("pool_k" in getattr(p[-1], "key", "") for p, _ in
-               jax.tree_util.tree_flatten_with_path(caches)[0])
-
-
 def _decode_in_place(params, x, cfg, caches, pos, kv_backend, count_mask):
     """The decode layer loop with every layer's caches as loop state: each
     block reads and updates its layer of the stacked caches in place (the
@@ -255,10 +250,16 @@ def forward(params, inputs, cfg: ModelConfig, rules=None, *, mode="train",
     (moe.route_counts).  `lengths` (B,): a padded prefill's sequence
     lengths (recurrent state, counted tokens).
 
-    Decode with `kv_backend` given (kernels/paged_attention's backends), on
-    a paged cache and unsharded (`rules` None), updates the caches in place
-    (_decode_in_place); otherwise decode slices each layer's cache and
-    gathers the pool in jnp."""
+    Decode follows the cache's layout: on a paged cache (init_cache(...,
+    "paged")) it updates every layer's caches in place (_decode_in_place)
+    through kernels/paged_attention, whose backend `kv_backend` picks (None:
+    the ops module's default for this platform); on a dense cache it runs
+    the generic layer loop.  Decode is unsharded: with `rules` given it
+    raises."""
+    if mode == "decode" and rules is not None:
+        raise NotImplementedError(
+            "decode under a mesh (rules given) is not supported: the "
+            "in-place decode is unsharded (ROADMAP R8)")
     x = embed(params["embed"], inputs, cfg)
     if cfg.embedding_multiplier != 1.0:
         x = x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
@@ -271,8 +272,8 @@ def forward(params, inputs, cfg: ModelConfig, rules=None, *, mode="train",
         out = _head(params, x, cfg, rules, new_caches, return_hidden)
         return out + (counts,) if count_routes else out
 
-    if (mode == "decode" and kv_backend is not None and rules is None
-            and _paged(caches)):
+    if mode == "decode" and any("pool_k" in c for c in (
+            *caches["layers"], caches.get("shared", {}))):
         return done(*_decode_in_place(params, x, cfg, caches, pos,
                                       kv_backend, count_mask))
     unit = cfg.block_pattern

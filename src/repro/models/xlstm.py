@@ -1,7 +1,7 @@
 """xLSTM blocks (arXiv:2405.04517): mLSTM (matrix memory, chunkwise-parallel)
 and sLSTM (scalar memory, strictly sequential scan with exponential-gating
 stabilizer).  Recurrent decode paths carry O(1) state per sequence — these are
-the sub-quadratic archs that make ``long_500k`` runnable.
+the sub-quadratic archs.
 
 mLSTM is implemented chunkwise (same segsum machinery as SSD): per-head scalar
 forget decay (log-sigmoid, hence stable cumulative sums) + exp input gate

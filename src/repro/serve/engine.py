@@ -37,6 +37,7 @@ import numpy as np
 from repro.configs.base import MAMBA2, MAMBA2_MOE, ModelConfig
 from repro.core import trace
 from repro.kernels.kv_compaction.ops import compact_kv_pool
+from repro.kernels.paged_attention import ops as paged_attention_ops
 from repro.models import forward, init_cache, init_params
 
 
@@ -56,22 +57,20 @@ class Request:
 
 class ServingEngine:
     def __init__(self, cfg: ModelConfig, params=None, *, max_slots: int = 4,
-                 max_seq: int = 256, seed: int = 0, rules=None,
+                 max_seq: int = 256, seed: int = 0,
                  scramble_blocks: bool = True,
                  kv_backend: Optional[str] = None):
         self.cfg = cfg
         self.max_slots = max_slots
         self.max_seq = max_seq
-        self.rules = rules
         self.scramble = scramble_blocks
         self.rng = np.random.default_rng(seed)
         self.params = params if params is not None else init_params(
             jax.random.PRNGKey(seed), cfg)
         self.caches = init_cache(cfg, max_slots, max_seq, "paged")
-        # the decode step's paged-attention kernel: the compiled one where
-        # the pool lives on a TPU, the jnp oracle elsewhere; tests may force
-        # "pallas_interpret"
-        self.kv_backend = kv_backend or _kv_backend_for(self.caches)
+        # the decode step's paged-attention backend; None: the ops module's
+        # default for this platform (tests may force "pallas_interpret")
+        self.kv_backend = paged_attention_ops.resolve_backend(kv_backend)
         # an MoE model's prefill and decode also return their routings to
         # held experts; its prefill, and a recurrent model's, is told the
         # prompt's length (the state stops there, padding is not counted)
@@ -92,7 +91,7 @@ class ServingEngine:
         self._rid = 0
 
         def decode_fn(params, caches, tokens, pos):
-            out = forward(params, tokens, cfg, rules, mode="decode",
+            out = forward(params, tokens, cfg, mode="decode",
                           caches=caches, pos=pos, kv_backend=self.kv_backend,
                           count_routes=self._counts)
             return (jnp.argmax(out[0][:, -1], axis=-1),) + out[1:]
@@ -100,7 +99,7 @@ class ServingEngine:
         self._decode = jax.jit(decode_fn, donate_argnums=(1,))
 
         def prefill_fn(params, caches, tokens, lengths=None):
-            return forward(params, tokens, cfg, rules, mode="prefill",
+            return forward(params, tokens, cfg, mode="prefill",
                            caches=caches, lengths=lengths,
                            count_routes=self._counts)
 
@@ -269,11 +268,6 @@ def prefill_buckets(max_seq: int, block: int) -> List[int]:
             out.append(b)
         b *= 2
     return out + [max_seq]
-
-
-def _kv_backend_for(caches) -> str:
-    device = next(iter(jax.tree.leaves(caches)[0].devices()))
-    return "pallas" if device.platform == "tpu" else "reference"
 
 
 def compact_caches(caches, backend: str):

@@ -8,7 +8,6 @@ Physical layout (DESIGN.md §4):
   batch          -> (pod?, data)              data parallel
   heads/ff/vocab/experts -> model             tensor / expert parallel
   fsdp (weight dim 0)    -> (pod?, data)      ZeRO-style param+opt sharding
-  cache_seq      -> model, or (pod?, data, model) for batch-1 long context
 Any axis that does not divide the dimension is dropped (guarded specs), so
 e.g. batch=1 decode falls back gracefully.
 """
@@ -81,9 +80,7 @@ class Rules:
             x, self.sharding(x.shape, *logical))
 
 
-def make_rules(mesh: Optional[Mesh], *, seq_shard_cache: bool = False) -> Rules:
-    """seq_shard_cache: shard the KV-cache sequence dim over (dp..., model) —
-    used for batch-1 long-context decode (distributed attention reduction)."""
+def make_rules(mesh: Optional[Mesh]) -> Rules:
     if mesh is None:
         return Rules(mesh=None)
     has_pod = "pod" in mesh.axis_names
@@ -97,7 +94,6 @@ def make_rules(mesh: Optional[Mesh], *, seq_shard_cache: bool = False) -> Rules:
         "ff": ("model",),
         "vocab": ("model",),
         "experts": ("model",),
-        "cache_seq": dp + ("model",) if seq_shard_cache else ("model",),
         "act_seq": ("model",),   # sequence-parallel residual stream
     }
     return Rules(mesh=mesh, table=table)
@@ -142,26 +138,6 @@ def param_spec(path, shape: Tuple[int, ...], rules: Rules) -> P:
     spec = rules.spec(*logical)
     if stacked:
         spec = P(None, *spec)
-    return fit_spec(shape, spec, rules.mesh)
-
-
-def cache_spec(path, shape: Tuple[int, ...], rules: Rules) -> P:
-    """Sharding spec for a KV/state-cache leaf (leading dim = layer repeats)."""
-    if rules.mesh is None:
-        return P()
-    name = path if isinstance(path, str) else path_str(path)
-    leaf = name.rsplit("/", 1)[-1]
-    if leaf in ("k", "v"):              # (reps, B, S, nkv, hd)
-        logical = ("batch", "cache_seq", None, None)
-    elif leaf in ("pool_k", "pool_v"):  # (reps, B, nblk, bs, nkv, hd)
-        logical = ("batch", "cache_seq", None, None, None)
-    elif leaf == "table":               # (reps, B, nblk)
-        logical = ("batch", None)
-    elif leaf in ("ssm", "C"):          # (reps, B, nh, ...)
-        logical = ("batch", "heads", None, None)
-    else:                               # small recurrent state
-        logical = ("batch",) + (None,) * (len(shape) - 2)
-    spec = P(None, *rules.spec(*logical))
     return fit_spec(shape, spec, rules.mesh)
 
 

@@ -1,6 +1,8 @@
 """Per-architecture smoke tests (deliverable f): reduced config of the same
 family, one forward + one train step on CPU, assert output shapes + no NaNs;
 plus prefill/decode == full-forward consistency for every cache layout."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -8,6 +10,7 @@ import pytest
 
 from repro.configs import ARCH_IDS, get
 from repro.models import forward, init_cache, init_params, lm_loss
+from repro.serve.engine import ServingEngine
 from repro.train.optimizer import adamw_update, init_opt_state
 
 KEY = jax.random.PRNGKey(0)
@@ -76,37 +79,125 @@ def test_prefill_decode_matches_forward(arch, layout):
                                rtol=3e-4, atol=3e-4)
 
 
+def _groups(caches):
+    """The cache groups (dicts of leaves stacked over layers or
+    applications), in tree order."""
+    return list(caches["layers"]) + ([caches["shared"]] if "shared" in caches
+                                     else [])
+
+
+def _through_table(group, b, n):
+    """(stack, n, nkv, hd) K and V of sequence b's first n tokens, read
+    from the paged pool through its table."""
+    table = np.asarray(group["table"])[:, b]                 # (stack, nblk)
+    t = np.arange(n)
+    bs = group["pool_k"].shape[3]
+    st = np.arange(table.shape[0])[:, None]
+    return tuple(np.asarray(group[k])[:, b][st, table[:, t // bs], t % bs]
+                 for k in ("pool_k", "pool_v"))
+
+
 @pytest.mark.parametrize("arch", ARCH_IDS)
 @pytest.mark.parametrize("kv_backend", ["reference", "pallas_interpret"])
-def test_decode_in_place_matches_the_gathering_decode(arch, kv_backend):
-    """Decode on every layer's caches in place (kv_backend given) gives the
-    logits and caches of the decode that slices each layer out, for every
-    block kind that rides the loop (attention, MoE, Mamba2, xLSTM, Zamba2's
-    shared attention).  The pools are compared whole, a free sequence's
-    (pos -1) rows too; its recurrent state, fed by its unused hidden
-    state, is left out."""
+def test_decode_in_place_matches_the_dense_decode(arch, kv_backend):
+    """Decode on every layer's paged caches in place gives the logits and
+    caches of the dense-cache decode of the same tokens, for every block
+    kind that rides the loop (attention, MoE, Mamba2, xLSTM, Zamba2's
+    shared attention).  The pools are read through their tables up to each
+    live sequence's position; a free sequence's (pos -1) pool rows and
+    every table are those before the step.  A free sequence's recurrent
+    state, fed by its unused hidden state, is left out."""
     cfg = get(arch, smoke=True).replace(param_dtype="float32",
                                         kv_block_size=8)
+    params = init_params(KEY, cfg)
+    B, S = 3, 16
+    x, _ = _inputs(cfg, B, S, extra=1)
+    pos = jnp.array([S, S - 3, -1], jnp.int32)
+    dense = init_cache(cfg, B, 32, "dense")
+    _, dense = forward(params, x[:, :S], cfg, mode="prefill", caches=dense)
+    ref, ref_cache = forward(params, x[:, S:S + 1], cfg, mode="decode",
+                             caches=dense, pos=pos)
+    paged = init_cache(cfg, B, 32, "paged")
+    _, paged = forward(params, x[:, :S], cfg, mode="prefill", caches=paged)
+    got, got_cache = forward(params, x[:, S:S + 1], cfg, mode="decode",
+                             caches=paged, pos=pos, kv_backend=kv_backend)
+    np.testing.assert_allclose(np.asarray(got[:2], np.float32),
+                               np.asarray(ref[:2], np.float32),
+                               rtol=1e-4, atol=1e-4)
+    close = functools.partial(np.testing.assert_allclose, rtol=1e-4,
+                              atol=1e-4)
+    for g, r, before in zip(*map(_groups, (got_cache, ref_cache, paged))):
+        if "pool_k" not in g:            # recurrent state: (stack, B, ...)
+            for k in g:
+                close(np.asarray(g[k], np.float32)[:, :2],
+                      np.asarray(r[k], np.float32)[:, :2])
+            continue
+        np.testing.assert_array_equal(np.asarray(g["table"]),
+                                      np.asarray(before["table"]))
+        for b in range(2):
+            n = int(pos[b]) + 1
+            k, v = _through_table(g, b, n)
+            close(k, np.asarray(r["k"])[:, b, :n])
+            close(v, np.asarray(r["v"])[:, b, :n])
+        for k in ("pool_k", "pool_v"):
+            np.testing.assert_array_equal(np.asarray(g[k])[:, 2],
+                                          np.asarray(before[k])[:, 2])
+
+
+def test_dense_decode_writes_nothing_for_a_free_sequence():
+    """A free sequence (pos -1) leaves its dense cache as it was, its last
+    row too: the write index is out of range and dropped, as the paged
+    pool's is."""
+    cfg = get("smollm_135m", smoke=True).replace(param_dtype="float32")
+    params = init_params(KEY, cfg)
+    B, S = 2, 16
+    x, _ = _inputs(cfg, B, S, extra=1)
+    cache = init_cache(cfg, B, 32, "dense")
+    _, cache = forward(params, x[:, :S], cfg, mode="prefill", caches=cache)
+    _, new = forward(params, x[:, S:S + 1], cfg, mode="decode", caches=cache,
+                     pos=jnp.array([S, -1], jnp.int32))
+    for a, b in zip(jax.tree.leaves(new), jax.tree.leaves(cache)):
+        a, b = np.asarray(a), np.asarray(b)
+        np.testing.assert_array_equal(a[:, 1], b[:, 1])
+        assert np.any(a[:, 0, S] != 0)             # the live one's token
+        np.testing.assert_array_equal(a[:, 0, S + 1:], b[:, 0, S + 1:])
+
+
+def test_decode_without_a_backend_runs_in_place():
+    """With no kv_backend, a paged decode runs in place on the ops module's
+    default backend, the jnp oracle off a TPU: bit-equal logits and caches
+    to kv_backend="reference", and a ServingEngine built without one
+    serves what one built with "reference" serves."""
+    assert jax.default_backend() != "tpu"
+    cfg = get("granite_4_0_h_small", smoke=True).replace(
+        param_dtype="float32", kv_block_size=8)
     params = init_params(KEY, cfg)
     B, S = 3, 16
     x, _ = _inputs(cfg, B, S, extra=1)
     cache = init_cache(cfg, B, 32, "paged")
     _, cache = forward(params, x[:, :S], cfg, mode="prefill", caches=cache)
     pos = jnp.array([S, S - 3, -1], jnp.int32)
-    ref, ref_cache = forward(params, x[:, S:S + 1], cfg, mode="decode",
-                             caches=cache, pos=pos)
-    got, got_cache = forward(params, x[:, S:S + 1], cfg, mode="decode",
-                             caches=cache, pos=pos, kv_backend=kv_backend)
-    np.testing.assert_allclose(np.asarray(got[:2], np.float32),
-                               np.asarray(ref[:2], np.float32),
-                               rtol=1e-4, atol=1e-4)
-    flat = jax.tree_util.tree_flatten_with_path(ref_cache)[0]
-    for (path, b), a in zip(flat, jax.tree.leaves(got_cache)):
-        rows = slice(None) if "pool" in jax.tree_util.keystr(path) \
-            else slice(0, 2)                   # every leaf: (layers, B, ...)
-        np.testing.assert_allclose(np.asarray(a, np.float32)[:, rows],
-                                   np.asarray(b, np.float32)[:, rows],
-                                   rtol=1e-4, atol=1e-4)
+    outs = [forward(params, x[:, S:S + 1], cfg, mode="decode", caches=cache,
+                    pos=pos, count_routes=True, **kw)
+            for kw in ({}, {"kv_backend": "reference"})]
+    for a, b in zip(*map(jax.tree.leaves, outs)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+    def serve(**kw):
+        eng = ServingEngine(cfg, params, max_slots=2, max_seq=128, seed=4,
+                            **kw)
+        reqs = [eng.submit(p, max_new=5) for p in ([5, 9, 2], [1, 2, 3, 4],
+                                                   [7, 7])]
+        eng.run_until_drained()
+        return eng, [r.out for r in reqs]
+
+    (default, served), (ref, ref_served) = serve(), serve(
+        kv_backend="reference")
+    assert default.kv_backend == "reference"
+    assert served == ref_served
+    for a, b in zip(jax.tree.leaves(default.caches),
+                    jax.tree.leaves(ref.caches)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 def test_decode_per_sequence_positions():

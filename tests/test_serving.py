@@ -77,7 +77,7 @@ def test_mid_stream_admission():
 
 def test_the_engine_picks_the_kernel_from_where_its_pool_lives():
     eng = ServingEngine(CFG, PARAMS, max_slots=2, max_seq=32, seed=0)
-    assert eng.kv_backend == "reference"           # the pool is on the CPU
+    assert eng.kv_backend == "reference"           # JAX runs on the CPU
 
 
 def test_pallas_interpret_engine_matches_the_reference_engine():

@@ -1,5 +1,6 @@
-"""Sharding rules + HLO analyzer unit tests, and an end-to-end multi-device
-train step run in a subprocess (device count must be set before jax init)."""
+"""Sharding rules unit tests, the refusal of a decode under a mesh, and an
+end-to-end multi-device train step run in a subprocess (device count must
+be set before jax init)."""
 import json
 import subprocess
 import sys
@@ -9,48 +10,11 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.launch.hlo_analysis import HloModule, analyze, type_bytes
+from repro.configs import get
+from repro.launch.mesh import make_host_mesh
+from repro.models import forward, init_cache, init_params
 from repro.sharding.rules import fit_spec, make_rules, param_spec
 from jax.sharding import AxisType, PartitionSpec as P
-
-
-def test_type_bytes():
-    assert type_bytes("bf16[128,128]{1,0}") == 128 * 128 * 2
-    assert type_bytes("(s32[], f32[4,2]{1,0})") == 4 + 32
-    assert type_bytes("pred[]") == 1
-    # replica_groups must NOT parse as a shape
-    assert type_bytes("replica_groups=[32,16]<=[512]") == 0
-
-
-def test_analyzer_counts_loop_trips_exactly():
-    def f(w, x):
-        def body(c, wi):
-            return c @ wi, ()
-        y, _ = jax.lax.scan(body, x, w)
-        return y
-
-    w = jax.ShapeDtypeStruct((8, 128, 128), jnp.float32)
-    x = jax.ShapeDtypeStruct((128, 128), jnp.float32)
-    c = jax.jit(f).lower(w, x).compile()
-    cost = analyze(c.as_text())
-    assert cost.flops == 8 * 2 * 128 ** 3
-
-
-def test_analyzer_nested_scans():
-    def f(w, x):
-        def outer(c, wi):
-            def inner(c2, _):
-                return c2 @ wi, ()
-            c2, _ = jax.lax.scan(inner, c, None, length=4)
-            return c2, ()
-        y, _ = jax.lax.scan(outer, x, w)
-        return y
-
-    w = jax.ShapeDtypeStruct((3, 64, 64), jnp.float32)
-    x = jax.ShapeDtypeStruct((64, 64), jnp.float32)
-    c = jax.jit(f).lower(w, x).compile()
-    cost = analyze(c.as_text())
-    assert cost.flops == 3 * 4 * 2 * 64 ** 3
 
 
 def _mesh():
@@ -74,6 +38,18 @@ def test_param_spec_routing():
     # biases/scales stay replicated
     sp = param_spec("layers/0/attn/wq_bias", (4, 64), rules)
     assert all(a is None for a in tuple(sp))
+
+
+def test_a_sharded_decode_is_refused():
+    """Decode is unsharded: under a mesh it raises rather than fall back
+    to a different algorithm."""
+    cfg = get("qwen3_8b", smoke=True)
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    caches = init_cache(cfg, 2, 32, "paged")
+    with pytest.raises(NotImplementedError, match="R8"):
+        forward(params, jnp.zeros((2, 1), jnp.int32), cfg,
+                make_rules(make_host_mesh()), mode="decode", caches=caches,
+                pos=jnp.array([3, -1], jnp.int32))
 
 
 SUBPROC = textwrap.dedent("""

@@ -104,7 +104,7 @@ LAYER_POOL_BYTES = (SLOTS * MAX_SEQ * SERVE_CFG.n_kv_heads * SERVE_CFG.hd
                     * 2)                # one layer's K pool in bf16: 134 MB
 
 
-def _decode_step(one_chip, **kw):
+def _decode_step(one_chip):
     def on_chip(tree):
         return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
             a.shape, a.dtype, sharding=one_chip), tree)
@@ -116,7 +116,8 @@ def _decode_step(one_chip, **kw):
 
     def step(params, caches, tokens, pos):     # ServingEngine's decode_fn
         logits, caches = forward(params, tokens, SERVE_CFG, None,
-                                 mode="decode", caches=caches, pos=pos, **kw)
+                                 mode="decode", caches=caches, pos=pos,
+                                 kv_backend="pallas")
         return jnp.argmax(logits[:, -1], axis=-1), caches
 
     tokens = jax.ShapeDtypeStruct((SLOTS, 1), jnp.int32, sharding=one_chip)
@@ -127,20 +128,12 @@ def _decode_step(one_chip, **kw):
 
 def test_paged_decode_step_compiles_in_place_for_v5e(one_chip):
     """The engine's decode step on the chip: the paged-attention kernel is
-    in it, and its temporaries stay under one layer's K pool, where the
-    step that gathers the whole pool every layer (forward with no
-    kv_backend, the next test; the serving engine's step before the
-    in-place kernels) needs 1,646,139,904 B of them."""
-    compiled = _decode_step(one_chip, kv_backend="pallas")
+    in it, and its temporaries stay under one layer's K pool (a step that
+    gathered the whole pool every layer needed 1,646,139,904 B of them)."""
+    compiled = _decode_step(one_chip)
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "paged_decode_attention" in text
     assert compiled.memory_analysis().temp_size_in_bytes < LAYER_POOL_BYTES
-
-
-def test_the_gathering_decode_step_holds_more_than_a_layers_pool(one_chip):
-    compiled = _decode_step(one_chip)
-    assert "paged_decode_attention" not in compiled.as_text()
-    assert compiled.memory_analysis().temp_size_in_bytes > LAYER_POOL_BYTES
 
 
 # granite-4.0-h at its published widths, one Mamba-2 + MoE layer and the
