@@ -10,9 +10,22 @@ Three-phase reads: compaction swaps the pool atomically per layer while the
 old pool stays valid, so in-flight lookups never see a hole.
 
 Scheduler: admit-on-free-slot continuous batching; one engine `step()` =
-(admit+prefill new requests) + (one lockstep decode token for every active
-sequence, ragged positions via the per-seq `pos` vector, -1 for a free
-slot).  Admission prefills a prompt padded to the smallest of a few bucket
+(admit+prefill new requests) + (dispatch one lockstep decode token for every
+active sequence that has tokens left, ragged positions via the per-seq `pos`
+vector, -1 for a free or finishing slot) + (fetch the previous step's tokens).
+One decode step is in flight: its input tokens are the last step's `nxt`,
+still on the device, with the prefill's first token in the slots admitted
+since; the host knows every position and which request ends when, so it
+never waits for a token value before dispatching.  A request's `out` gains
+a step's token in the next `step()`, once the decode after it is
+dispatched, and a request enters `finished` (and leaves `active`, freeing
+its slot) only when its last token is on the host.  What drains the
+pipeline: an admission (its prefill follows the decode in flight and its
+first token is synced) and an engine with no token left to decode; a
+compaction waits for the device to finish the decode in flight (its tokens
+stay unfetched), so that its copies of the pool add to no buffer still in
+use and the HBM peak is that of a step that syncs.
+Admission prefills a prompt padded to the smallest of a few bucket
 lengths that holds it (`prefill_buckets`), one compiled prefill a bucket.
 The decode step reads and writes the pool in place through
 kernels/paged_attention: each active sequence's live blocks, none of a free
@@ -89,14 +102,33 @@ class ServingEngine:
         self.decode_steps = 0
         self.compactions = 0
         self._rid = 0
+        # the last decode's next tokens, on the device; the prefill's first
+        # token of each slot admitted since (-1: none)
+        self._last = jnp.zeros(max_slots, jnp.int32)
+        self._first = np.full(max_slots, -1, np.int32)
+        # the decode dispatched and not yet fetched: its next tokens, route
+        # counts (traced only), serve.step span id and the slots it decoded
+        self._inflight = None
 
-        def decode_fn(params, caches, tokens, pos):
+        def decode_tokens(params, caches, tokens, pos):
             out = forward(params, tokens, cfg, mode="decode",
                           caches=caches, pos=pos, kv_backend=self.kv_backend,
                           count_routes=self._counts)
             return (jnp.argmax(out[0][:, -1], axis=-1),) + out[1:]
 
-        self._decode = jax.jit(decode_fn, donate_argnums=(1,))
+        # the model's decode of given tokens, traced into decode_fn (an
+        # attribute of its own, so that a test can substitute a faulty one)
+        self._decode = decode_tokens
+
+        def decode_fn(params, caches, last, first, pos):
+            if cfg.input_kind == "embeds":
+                tokens = jnp.zeros((max_slots, 1, cfg.d_model),
+                                   jnp.dtype(cfg.param_dtype))
+            else:
+                tokens = jnp.where(first >= 0, first, last)[:, None]
+            return self._decode(params, caches, tokens, pos)
+
+        self._decode_next = jax.jit(decode_fn, donate_argnums=(1,))
 
         def prefill_fn(params, caches, tokens, lengths=None):
             return forward(params, tokens, cfg, mode="prefill",
@@ -150,6 +182,7 @@ class ServingEngine:
                         np.asarray(counts[0])[0]))
                 req.out.append(nxt)
                 self._write_slot_cache(slot, sub)
+            self._first[slot] = nxt
             self.pos[slot] = plen
             self.active[slot] = req
 
@@ -174,50 +207,62 @@ class ServingEngine:
 
     def _step(self, sid=None) -> int:
         self._admit()
-        if not self.active:
-            return 0
-        tokens = np.zeros((self.max_slots, 1), np.int32)
+        # a request is decoded max_new times (once at least) from the
+        # position after its prompt; a slot whose last decode is dispatched
+        # waits for its token, undecoded
+        slots = [slot for slot, req in self.active.items()
+                 if self.pos[slot] < len(req.prompt) + max(req.max_new, 1)]
+        landing, self._inflight = self._inflight, None
+        if slots:
+            self._inflight = self._dispatch(slots, sid, landing is not None)
+        return self._land(*landing) if landing else 0
+
+    def _dispatch(self, slots, sid, in_flight: bool):
+        """Dispatches one decode of `slots` without waiting for it; returns
+        what `_land` needs to fetch its tokens."""
         pos = np.full(self.max_slots, -1, np.int32)
-        for slot, req in self.active.items():
-            tokens[slot, 0] = req.out[-1]
-            pos[slot] = self.pos[slot]
+        pos[slots] = self.pos[slots]
         if sid is not None:
             # per layer: the blocks the decode step's kernel reads (a
             # sequence's ceil((pos + 1) / bs)), and the pool's
             bs = self.cfg.kv_block_size
             live = pos[pos >= 0].astype(np.int64)
             trace.tag(sid, kv_blocks=int(np.sum(-(-(live + 1) // bs))),
-                      pool_blocks=self.max_slots * (self.max_seq // bs))
+                      pool_blocks=self.max_slots * (self.max_seq // bs),
+                      in_flight=int(in_flight))
             if self._state_bytes_per_slot:
                 # recurrent state of the active slots, read and written
                 trace.tag(sid, ssm_state_bytes=2 * len(live)
                           * self._state_bytes_per_slot)
-        if self.cfg.input_kind == "embeds":
-            tok_in = jnp.zeros((self.max_slots, 1, self.cfg.d_model),
-                               jnp.dtype(self.cfg.param_dtype))
-        else:
-            tok_in = jnp.asarray(tokens)
-        nxt, self.caches, *counts = self._decode(
-            self.params, self.caches, tok_in, jnp.asarray(pos))
-        nxt = np.asarray(nxt)
-        if counts and sid is not None:
-            routes, hit = np.asarray(counts[0])
+        nxt, self.caches, *counts = self._decode_next(
+            self.params, self.caches, self._last, self._first, pos)
+        # a fresh array: the one just passed may still be read by the call
+        self._last, self._first = nxt, np.full(self.max_slots, -1, np.int32)
+        self.pos[slots] += 1
+        self.decode_steps += 1
+        return (nxt, counts[0] if counts and sid is not None else None, sid,
+                slots)
+
+    def _land(self, nxt, counts, sid, slots) -> int:
+        """Fetches a dispatched decode's tokens, and when traced its route
+        counts (tagged on the serve.step that dispatched it), and appends
+        them; a request whose last token this is finishes and frees its
+        slot.  Returns the tokens fetched."""
+        nxt, counts = jax.device_get((nxt, counts))
+        if counts is not None:
+            routes, hit = counts
             trace.tag(sid, moe_routes_local=int(routes),
                       moe_experts_hit=int(hit))
-        produced = 0
-        for slot in list(self.active):
+        for slot in slots:
             req = self.active[slot]
-            self.pos[slot] += 1
             req.out.append(int(nxt[slot]))
-            produced += 1
             if len(req.out) - 1 >= req.max_new:
                 req.done = True
                 req.finished = time.perf_counter()
                 self.finished.append(req)
                 del self.active[slot]
                 self.free_slots.append(slot)
-        self.decode_steps += 1
-        return produced
+        return len(slots)
 
     def run_until_drained(self, max_steps: int = 10_000) -> int:
         total = 0
@@ -247,6 +292,10 @@ class ServingEngine:
         """Nezha GC for the KV pool (see `compact_caches`); `backend` picks
         the compaction kernel (repro.kernels.kv_compaction.ops)."""
         with trace.span("serve.compact"):
+            # the work still queued (the decode in flight, an admission's
+            # slot writes) holds buffers the host has let go; the copies of
+            # the pool wait for it, so that they do not stack on those
+            jax.block_until_ready(self.caches)
             self.caches = compact_caches(self.caches, backend)
         self.compactions += 1
 

@@ -19,7 +19,8 @@ from repro.core import trace
 from repro.models import forward, init_cache, init_params
 from repro.models import moe as moe_mod
 from repro.serve.engine import ServingEngine
-from test_serving import admit_both_ways, prompt_kv
+from test_serving import (admit_both_ways, forced_greedy, prompt_kv,
+                          serve_staggered)
 
 _REF = Path(__file__).resolve().parents[1] / "benchmarks" / "chip" / \
     "reference" / "granite4h.py"
@@ -317,3 +318,37 @@ def test_a_bucketed_admission_equals_one_padded_to_max_seq(monkeypatch):
                      ((g["ssm"], w["ssm"]), (g["conv"], w["conv"])))
             for a, b in pairs:
                 np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("kv_backend", ["reference", "pallas_interpret"])
+def test_pipelined_served_tokens_are_the_greedy_tokens(kv_backend):
+    """One step in flight, admissions mid-stream, a reused slot and a
+    compaction: every served token, the last included, is the full
+    forward's greedy token."""
+    eng = ServingEngine(CFG, PARAMS, max_slots=3, max_seq=MAX_SEQ, seed=11,
+                        kv_backend=kv_backend)
+    for r in serve_staggered(eng, compact_after=3):
+        assert r.out == forced_greedy(CFG, PARAMS, r), r.rid
+
+
+def test_lagged_route_counts_tag_their_own_step(monkeypatch):
+    """Traced, each serve.step that dispatches a decode carries that
+    decode's routings (fetched a step later) and in_flight: 0 on the
+    first, 1 on each consecutive one.  The active slots fall 3, 2, 1 (one
+    KV block each), so a count tagged on a neighbouring step would not
+    match its kv_blocks."""
+    t = trace.Tracer()
+    monkeypatch.setattr(trace, "_ACTIVE", t)
+    eng = ServingEngine(CFG, PARAMS, max_slots=3, max_seq=MAX_SEQ, seed=12)
+    for m in (2, 4, 6):                     # positions 2-7: one block
+        eng.submit([7, 3], max_new=m)
+    eng.run_until_drained()
+    steps = [s for s in t.spans if s.name == "serve.step"]
+    dispatched = [s for s in steps if "kv_blocks" in s.tags]
+    assert [s.tags["kv_blocks"] for s in dispatched] == [3, 3, 2, 2, 1, 1]
+    assert [s.tags["in_flight"] for s in dispatched] == [0, 1, 1, 1, 1, 1]
+    for s in dispatched:
+        assert s.tags["moe_routes_local"] == \
+            s.tags["kv_blocks"] * CFG.n_layers * CFG.top_k
+    # the last step only fetches: no decode of its own to tag
+    assert steps[-1].tags == {}

@@ -22,6 +22,16 @@ def ref_generate(prompt, n):
     return toks[len(prompt):]
 
 
+def forced_greedy(cfg, params, req):
+    """The full forward's greedy token after each prefix of the request's
+    prompt and served tokens, in one teacher-forced call: equal to
+    `req.out` when every served token is the greedy one."""
+    seq = req.prompt + req.out[:-1]
+    logits, _ = forward(params, jnp.asarray([seq]), cfg, mode="train")
+    return np.asarray(jnp.argmax(logits[0, len(req.prompt) - 1:],
+                                 axis=-1)).tolist()
+
+
 def test_continuous_batching_matches_reference():
     eng = ServingEngine(CFG, PARAMS, max_slots=3, max_seq=64, seed=0,
                         scramble_blocks=True)
@@ -227,3 +237,84 @@ def test_a_bucketed_admission_equals_one_padded_to_max_seq():
             for a, b in zip(prompt_kv(g, n, QWEN3.kv_block_size),
                             prompt_kv(w, n, QWEN3.kv_block_size)):
                 np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5)
+
+
+def serve_staggered(eng, compact_after=None):
+    """Five requests on the engine's slots: three at once, two more after
+    two steps (admissions mid-stream and, on few slots, slot reuse), a
+    compaction after `compact_after` steps; after every step, each
+    finished request holds all its tokens, an active one not more, and
+    an engine with none active has nothing in flight.  Returns the
+    requests once drained."""
+    prompts = ([5, 9, 2, 7], [1, 2, 3], [11, 4, 6, 8, 10], [3, 3, 3],
+               [9, 1])
+    lengths = (6, 3, 8, 5, 4)
+    reqs = [eng.submit(p, max_new=m)
+            for p, m in zip(prompts[:3], lengths[:3])]
+    steps = 0
+    while eng.active or eng.queue or steps < 2:
+        eng.step()
+        steps += 1
+        if steps == 2:
+            reqs += [eng.submit(p, max_new=m)
+                     for p, m in zip(prompts[3:], lengths[3:])]
+        if steps == compact_after:
+            eng.compact(backend="reference")
+        assert all(r.done and len(r.out) == r.max_new + 1
+                   for r in eng.finished)
+        assert all(len(r.out) <= r.max_new for r in eng.active.values())
+        assert eng.active or eng._inflight is None
+    assert len(eng.finished) == len(reqs)
+    return reqs
+
+
+def test_the_next_decode_is_dispatched_before_the_last_ones_tokens_are_fetched():
+    """Every decode but the last is followed by the next one's dispatch
+    before its own tokens are fetched, across admissions mid-stream and
+    slot reuse (three slots: some slot always has a token left to decode
+    until the end); each decode's tokens are fetched once, in order."""
+    eng = ServingEngine(CFG, PARAMS, max_slots=3, max_seq=64, seed=6)
+    dispatched, events = [], []
+    decode_next, land = eng._decode_next, eng._land
+
+    def dispatch(*a):
+        out = decode_next(*a)
+        dispatched.append(out[0])
+        events.append(("dispatch", len(dispatched) - 1))
+        return out
+
+    def fetch(nxt, *a):
+        k = next(i for i, x in enumerate(dispatched) if x is nxt)
+        events.append(("fetch", k))
+        return land(nxt, *a)
+
+    eng._decode_next, eng._land = dispatch, fetch
+    serve_staggered(eng)
+    n = len(dispatched)
+    assert [k for e, k in events if e == "fetch"] == list(range(n))
+    for k in range(n - 1):
+        assert events.index(("dispatch", k + 1)) < \
+            events.index(("fetch", k)), events
+    assert events[-1] == ("fetch", n - 1)
+
+
+def test_finished_requests_hold_all_their_tokens_after_every_step():
+    """On two slots (three of five requests wait for a reused slot), with
+    a compaction midway: see serve_staggered's checks after each step."""
+    eng = ServingEngine(CFG, PARAMS, max_slots=2, max_seq=64, seed=7,
+                        scramble_blocks=True)
+    reqs = serve_staggered(eng, compact_after=4)
+    assert eng.compactions == 1
+    for r in reqs:
+        assert r.out == forced_greedy(CFG, PARAMS, r), r.rid
+
+
+@pytest.mark.parametrize("backend", ["reference", "pallas_interpret"])
+def test_served_tokens_are_the_greedy_tokens_qwen3(backend):
+    """A Qwen3-shaped model, one step in flight: every served token,
+    the last included, is the full forward's greedy token."""
+    params = init_params(jax.random.PRNGKey(2), QWEN3)
+    eng = ServingEngine(QWEN3, params, max_slots=3, max_seq=64, seed=9,
+                        kv_backend=backend)
+    for r in serve_staggered(eng, compact_after=3):
+        assert r.out == forced_greedy(QWEN3, params, r), r.rid

@@ -114,16 +114,16 @@ def _decode_step(one_chip):
     caches = on_chip(jax.eval_shape(
         lambda: init_cache(SERVE_CFG, SLOTS, MAX_SEQ, "paged")))
 
-    def step(params, caches, tokens, pos):     # ServingEngine's decode_fn
+    def step(params, caches, last, first, pos):     # ServingEngine's
+        tokens = jnp.where(first >= 0, first, last)[:, None]   # decode_fn
         logits, caches = forward(params, tokens, SERVE_CFG, None,
                                  mode="decode", caches=caches, pos=pos,
                                  kv_backend="pallas")
         return jnp.argmax(logits[:, -1], axis=-1), caches
 
-    tokens = jax.ShapeDtypeStruct((SLOTS, 1), jnp.int32, sharding=one_chip)
-    pos = jax.ShapeDtypeStruct((SLOTS,), jnp.int32, sharding=one_chip)
-    return jax.jit(step, donate_argnums=(1,)).lower(params, caches, tokens,
-                                                   pos).compile()
+    i32 = jax.ShapeDtypeStruct((SLOTS,), jnp.int32, sharding=one_chip)
+    return jax.jit(step, donate_argnums=(1,)).lower(params, caches, i32, i32,
+                                                   i32).compile()
 
 
 def test_paged_decode_step_compiles_in_place_for_v5e(one_chip):
@@ -158,15 +158,16 @@ def test_granite_decode_step_updates_its_state_in_place_for_v5e(one_chip):
     caches = on_chip(jax.eval_shape(
         lambda: init_cache(GRANITE, GRANITE_SLOTS, GRANITE_SEQ, "paged")))
 
-    def step(params, caches, tokens, pos):     # ServingEngine's decode_fn
+    def step(params, caches, last, first, pos):     # ServingEngine's
+        tokens = jnp.where(first >= 0, first, last)[:, None]   # decode_fn
         out = forward(params, tokens, GRANITE, None, mode="decode",
                       caches=caches, pos=pos, kv_backend="pallas",
                       count_routes=True)
         return (jnp.argmax(out[0][:, -1], axis=-1),) + out[1:]
 
-    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
+    i32 = jax.ShapeDtypeStruct((GRANITE_SLOTS,), jnp.int32, sharding=one_chip)
     compiled = jax.jit(step, donate_argnums=(1,)).lower(
-        params, caches, i32(GRANITE_SLOTS, 1), i32(GRANITE_SLOTS)).compile()
+        params, caches, i32, i32, i32).compile()
     assert "paged_decode_attention" in compiled.as_text()
     m = compiled.memory_analysis()
     assert m.temp_size_in_bytes < LAYER_STATE_BYTES
